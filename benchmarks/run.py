@@ -52,8 +52,8 @@ def _results_dir() -> Path:
 def _ensure_host_devices(n: int):
     """The node sweep emulates ``n`` sockets; this must run before anything
     initializes the JAX backend (importing repro.node does not)."""
-    from repro.node.topology import ensure_emulated_sockets
-    ensure_emulated_sockets(n)
+    from repro.node.topology import ensure_node_devices
+    ensure_node_devices(n)
 
 
 def emit(name: str, us_per_call: float, derived: str):
@@ -1290,6 +1290,8 @@ def main(argv=None) -> None:
                          "is given with no value)", nargs="?",
                     const="__default__")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     if args.trace_out is not None:
         obs_trace.enable()
     if args.sweep_node or args.sweep_prefill:

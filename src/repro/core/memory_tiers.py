@@ -100,6 +100,24 @@ TPU_V5E_NODE = MachineTiers(
 
 MACHINES = {m.name: m for m in (SN40L_NODE, DGX_A100, DGX_H100, TPU_V5E_NODE)}
 
+# ``device_kind`` as JAX reports it -> the node preset that models that chip
+MACHINES_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E_NODE}
+
+
+def machine_for(devices) -> MachineTiers:
+    """The node preset for the devices a node runs on. On an accelerator it
+    comes from ``MACHINES_BY_DEVICE_KIND``, and a kind missing there is an
+    error, not a default. Emulated CPU sockets model a TPU v5e node."""
+    d = devices[0]
+    if d.platform == "cpu":
+        return TPU_V5E_NODE
+    try:
+        return MACHINES_BY_DEVICE_KIND[d.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no MachineTiers preset for device kind {d.device_kind!r}; "
+            f"add one to MACHINES_BY_DEVICE_KIND") from None
+
 
 # ----------------------------------------------------------------------
 # Serving-time HBM split: expert weights vs paged KV pool

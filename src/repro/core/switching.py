@@ -197,18 +197,26 @@ class HBMWeightCache:
             return jax.device_put(host_tree, self.device)
         return jax.device_put(host_tree)
 
-    def _load_job(self, expert_id: str) -> _Loaded:
-        """Worker-side load: store read, then H2D copy. No shared-state
-        mutation here — the consuming (caller) thread owns the books."""
+    def _read(self, expert_id: str):
+        """Capacity-tier read: (host pytree, seconds)."""
         t0 = time.perf_counter()
         with trace.span("store_read", cat="switch", expert=expert_id):
             host = self.store.get(expert_id)
-        t1 = time.perf_counter()
+        return host, time.perf_counter() - t0
+
+    def _h2d(self, expert_id: str, host, read_s: float) -> _Loaded:
+        """Host -> HBM copy of one expert, waited to completion."""
+        t0 = time.perf_counter()
         with trace.span("h2d", cat="switch", expert=expert_id):
             dev = self._put_device(host)
             jax.block_until_ready(dev)
-        t2 = time.perf_counter()
-        return _Loaded(dev, tree_bytes(host), t1 - t0, t2 - t1)
+        return _Loaded(dev, tree_bytes(host), read_s, time.perf_counter() - t0)
+
+    def _load_job(self, expert_id: str) -> _Loaded:
+        """Worker-side prefetch load: store read, then H2D copy into room
+        the prefetch reserved up front. No shared-state mutation here — the
+        consuming (caller) thread owns the books."""
+        return self._h2d(expert_id, *self._read(expert_id))
 
     def _retire(self, name: str, entry: _Entry):
         """Account one entry leaving HBM (eviction or drop): write back
@@ -336,9 +344,13 @@ class HBMWeightCache:
         if loaded is None:
             # true miss: load inline on the caller thread — submitting to
             # the (max_inflight-sized) executor would queue the critical
-            # path behind in-flight prefetches of OTHER experts
+            # path behind in-flight prefetches of OTHER experts. Room is
+            # made between the store read and the H2D copy, so HBM never
+            # holds the tier's capacity plus the incoming expert
             self.stats.misses += 1
-            loaded = self._load_job(expert_id)
+            host, read_s = self._read(expert_id)
+            self._make_room(tree_bytes(host))
+            loaded = self._h2d(expert_id, host, read_s)
         cause = "prefetch" if consumed_prefetch else (
             "failed_prefetch" if failed_wait_s else "miss")
         value = self._finish_load(expert_id, loaded, read_only, cause=cause)

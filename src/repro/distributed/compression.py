@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed.ctx import shard_map
-
 
 def quantize_int8(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     scale = jnp.max(jnp.abs(x)) / 127.0 + 1e-12
@@ -54,7 +52,7 @@ def make_compressed_allreduce(mesh: Mesh, axis: str = "data"):
     def _one(g):
         def body(gl):
             return compressed_psum_int8(gl, axis)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=P(axis), out_specs=P(),
             check_vma=False,
         )(g)

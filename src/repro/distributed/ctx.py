@@ -15,15 +15,6 @@ from typing import Optional
 import jax
 from jax.sharding import PartitionSpec
 
-try:                                   # public API, jax >= 0.6
-    shard_map = jax.shard_map
-except AttributeError:                 # jax 0.4.x: experimental, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_expt
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_expt(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=check_vma)
-
 _ACTIVATION_PSPEC: Optional[PartitionSpec] = None
 _NAMED: dict = {}
 
@@ -57,20 +48,14 @@ def constrain(h):
     """Apply the active activation constraint to a (B, S, D) residual."""
     if _ACTIVATION_PSPEC is None:
         return h
-    try:
-        return jax.lax.with_sharding_constraint(h, _ACTIVATION_PSPEC)
-    except Exception:
-        return h
+    return jax.lax.with_sharding_constraint(h, _ACTIVATION_PSPEC)
 
 
 def constrain_named(name: str, x):
     p = _NAMED.get(name)
     if p is None:
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, p)
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, p)
 
 
 # ----------------------------------------------------------------------

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed.ctx import shard_map
-
 
 def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jnp.ndarray,
                    mesh: Mesh, *, axis: str = "stage"):
@@ -71,7 +69,7 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jnp.ndarray,
         # only the last stage holds real outputs; psum replicates them
         return jax.lax.psum(outs, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec_params, P(None)),   # x replicated; stage 0 reads it
         out_specs=P(None),

@@ -146,14 +146,19 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
 def _decode_paged_kernel(tables_ref, len1_ref, q_ref, k_ref, v_ref, o_ref,
                          acc_ref, m_ref, l_ref, *, block, scale):
-    """One (lane, kv-head) pair streams its block table sequentially over the
-    innermost grid axis; (m, l, acc) online-softmax state persists in VMEM
-    across the blocks (same scheme as ``_decode_kernel``, but the KV tile for
-    step ``j`` is pool row ``tables[b, j]`` — gathered by the BlockSpec index
-    map off the scalar-prefetched table, never materialized contiguously)."""
+    """One lane streams its block table sequentially over the innermost grid
+    axis; (m, l, acc) online-softmax state persists in VMEM across the
+    blocks (same scheme as ``_decode_kernel``, but the KV tile for step
+    ``j`` is pool row ``tables[b, j]`` — gathered by the BlockSpec index map
+    off the scalar-prefetched table, never materialized contiguously).
+
+    The tile holds every kv head of the pool block, so its minor dims are
+    the pool's own ``(Hkv, dh)``. Scores are lane reductions of q * k on
+    the vector unit: the (block, Hkv) layout never has to be transposed or
+    split per head, whatever Hkv is."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
+    j = pl.program_id(1)
+    nb = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
@@ -163,39 +168,38 @@ def _decode_paged_kernel(tables_ref, len1_ref, q_ref, k_ref, v_ref, o_ref,
 
     length = len1_ref[b]
 
-    # blocks past the lane's length are fully masked: skip the math (their
-    # tile DMA still happens — tables are padded with the scratch row, so the
-    # fetch is cheap and always in-bounds)
+    # blocks past the lane's length are fully masked: skip the math (tables
+    # are padded with the scratch row, so their fetch is always in-bounds,
+    # and repeated padding indices elide the DMA)
     @pl.when(j * block < length)
     def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32) * scale        # (G, dh)
-        k = k_ref[0, :, 0]                                 # (block, dh)
-        v = v_ref[0, :, 0]
-        G = q.shape[0]
-        s = jnp.dot(q, k.astype(jnp.float32).T)            # (G, block)
-        kpos = j * block + jax.lax.broadcasted_iota(jnp.int32, (G, block), 1)
+        q = q_ref[0].astype(jnp.float32) * scale           # (G, Hkv, dh)
+        k = k_ref[0].astype(jnp.float32)                   # (block, Hkv, dh)
+        v = v_ref[0].astype(jnp.float32)
+        s = jnp.sum(q[:, None] * k[None], axis=-1,
+                    keepdims=True)                         # (G, block, Hkv, 1)
+        kpos = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = kpos < length
         s = jnp.where(mask, s, NEG_INF)
-        m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, s.max(-1))
+        m_old = m_ref[...]                                 # (G, Hkv, 1)
+        m_new = jnp.maximum(m_old, s.max(axis=1))
         p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
         alpha = jnp.exp(m_old - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v[None], axis=1)
         m_ref[...] = m_new
 
     @pl.when(j == nb - 1)
     def _done():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def flash_decode_paged(q, k_pool, v_pool, tables, len1, *, interpret=False):
     """Paged-native flash decode: gather K/V straight from the block pool.
 
-    q       (B, Hkv, G, dh)  — GQA-grouped queries (G = Hq // Hkv)
+    q       (B, G, Hkv, dh)  — GQA-grouped queries (G = Hq // Hkv), group
+            index major so each tile's kv heads line up with q's
     pools   (rows, block, Hkv, dh) — the ``PagedKVCache`` k/v arrays
     tables  (B, maxb) int32  — per-lane block tables, padded with the pool's
             scratch row (every entry must be a valid row index)
@@ -204,14 +208,16 @@ def flash_decode_paged(q, k_pool, v_pool, tables, len1, *, interpret=False):
             always — empty/inactive lanes attend their padding rows and
             produce finite garbage the caller ignores, exactly like the XLA
             paged-extend reference)
-    Returns (B, Hkv, G, dh).
+    Returns (B, G, Hkv, dh).
 
     ``tables``/``len1`` ride the scalar-prefetch channel
     (``PrefetchScalarGridSpec``) so the KV BlockSpec index map resolves
     ``tables[b, j]`` BEFORE the tile DMA is issued — the vLLM-style
-    block-sparse gather, expressed as a data-dependent index map.
+    block-sparse gather, expressed as a data-dependent index map. One grid
+    step fetches a whole pool block (all kv heads), so the tile's last two
+    dims are the pool's ``(Hkv, dh)`` at any ``block``.
     """
-    B, Hkv, G, dh = q.shape
+    B, G, Hkv, dh = q.shape
     rows, block, Hkv_p, _ = k_pool.shape
     assert Hkv_p == Hkv, (Hkv_p, Hkv)
     maxb = tables.shape[1]
@@ -219,27 +225,26 @@ def flash_decode_paged(q, k_pool, v_pool, tables, len1, *, interpret=False):
                                scale=1.0 / math.sqrt(dh))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, maxb),
+        grid=(B, maxb),
         in_specs=[
-            pl.BlockSpec((1, 1, G, dh),
-                         lambda b, h, j, tr, lr: (b, h, 0, 0)),
-            pl.BlockSpec((1, block, 1, dh),
-                         lambda b, h, j, tr, lr: (tr[b, j], 0, h, 0)),
-            pl.BlockSpec((1, block, 1, dh),
-                         lambda b, h, j, tr, lr: (tr[b, j], 0, h, 0)),
+            pl.BlockSpec((1, G, Hkv, dh), lambda b, j, tr, lr: (b, 0, 0, 0)),
+            pl.BlockSpec((1, block, Hkv, dh),
+                         lambda b, j, tr, lr: (tr[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, block, Hkv, dh),
+                         lambda b, j, tr, lr: (tr[b, j], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, dh),
-                               lambda b, h, j, tr, lr: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, G, Hkv, dh),
+                               lambda b, j, tr, lr: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, dh), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G, Hkv, dh), jnp.float32),
+            pltpu.VMEM((G, Hkv, 1), jnp.float32),
+            pltpu.VMEM((G, Hkv, 1), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, G, Hkv, dh), q.dtype),
         interpret=interpret,
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(len1, jnp.int32),
       q, k_pool, v_pool)

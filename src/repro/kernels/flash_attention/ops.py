@@ -51,13 +51,13 @@ def decode_paged(q, k_pool, v_pool, tables, len1, *, interpret=None):
     k_pool/v_pool (rows, block, Hkv, dh) — ``PagedKVCache`` arrays;
     tables (B, maxb) int32 padded with the scratch row; len1 (B,) int32 =
     per-lane valid positions (length + 1 after this step's scatter).
-    Returns (B, Hq, dh). Queries are grouped (B, Hkv, G, dh) so each kv
-    head's tile serves its whole q-head group from one gather.
+    Returns (B, Hq, dh). Queries are grouped (B, G, Hkv, dh) so each pool
+    block's tile serves every q head from one gather.
     """
     B, Hq, dh = q.shape
     Hkv = k_pool.shape[2]
     G = Hq // Hkv
-    qg = q.reshape(B, Hkv, G, dh)
+    qg = q.reshape(B, Hkv, G, dh).transpose(0, 2, 1, 3)
     o = flash_decode_paged(qg, k_pool, v_pool, tables, len1,
                            interpret=_interp(interpret))
-    return o.reshape(B, Hq, dh)
+    return o.transpose(0, 2, 1, 3).reshape(B, Hq, dh)

@@ -1,32 +1,19 @@
-"""Shared kernel-runtime knobs for every Pallas package.
+"""Shared kernel-runtime decision for every Pallas package.
 
-Interpret-mode selection used to be a copy-pasted ``_interp`` helper in each
-``kernels/*/ops.py``; it is now ONE documented knob:
-
-  * ``interpret=None`` (the default everywhere) auto-detects: compiled via
-    Mosaic on TPU, interpreter on every other backend (this container's CPU
-    CI runs every kernel — including the paged decode path — through the
-    interpreter).
-  * ``interpret=True/False`` forces the mode for one call.
-  * ``REPRO_PALLAS_INTERPRET=0/1`` (env var) overrides the auto-detection
-    process-wide — e.g. ``=1`` to debug a Mosaic miscompile on TPU with the
-    interpreter, ``=0`` to assert nothing silently falls back. An explicit
-    per-call ``interpret=`` still wins over the env var.
+``interpret=None`` (the default everywhere) lets the platform decide:
+compiled through Mosaic on a TPU, the Pallas interpreter on every other
+backend (the CPU test suite runs every kernel, the paged decode path
+included, through the interpreter). ``interpret=True/False`` forces the
+mode for one call. Nothing process-wide can turn the chip path into the
+interpreter.
 """
 from __future__ import annotations
 
-import os
-
 import jax
-
-_ENV = "REPRO_PALLAS_INTERPRET"
 
 
 def resolve_interpret(interpret=None) -> bool:
     """The single interpret-mode decision for all kernel ops wrappers."""
     if interpret is not None:
         return bool(interpret)
-    env = os.environ.get(_ENV)
-    if env is not None and env != "":
-        return env.lower() not in ("0", "false", "no")
     return jax.default_backend() != "tpu"

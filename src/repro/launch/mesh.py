@@ -6,17 +6,10 @@ jax device state.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:                                    # jax >= 0.5 explicit-axis-types API
-    from jax.sharding import AxisType
-except ImportError:                     # jax 0.4.x: Auto is the only behaviour
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _mk(shape, axes) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -93,24 +93,31 @@ def build_coe(cfg, n_experts: int, hbm_experts: float, seed: int = 0,
 
 def build_experts(cfg, n_experts: int, seed: int = 0):
     """Host-side expert pytrees: cheap fine-tune stand-ins (per-expert
-    perturbations of one base init)."""
+    perturbations of one base init). The base is initialized and each
+    expert perturbed on the device one leaf at a time, so host RAM holds
+    only the finished experts (no float64 temporaries)."""
     import jax
+    import jax.numpy as jnp
     from repro.models import get_model
 
-    model = get_model(cfg)
     rng = jax.random.PRNGKey(seed)
-    base = model.init(rng)
-    host_base = jax.tree.map(np.asarray, base)
-    nbytes = sum(x.nbytes for x in jax.tree.leaves(host_base))
+    leaves, treedef = jax.tree.flatten(get_model(cfg).init(rng))
+    nbytes = sum(x.nbytes for x in leaves)
+
+    @jax.jit
+    def perturb(x, key):
+        noise = jax.random.normal(key, x.shape, jnp.float32) * 0.01
+        return x + noise.astype(x.dtype)
+
     domains = ["code", "math", "translate", "chat", "legal", "medical"]
     out = []
     for i in range(n_experts):
-        rs = np.random.RandomState(i)
-        pert = jax.tree.map(
-            lambda x: (x + (rs.standard_normal(x.shape) * 0.01).astype(x.dtype))
-            if x.dtype in (np.float32, np.float16) or x.dtype.str == "<V2"
-            else x, host_base)
-        out.append((f"expert{i:03d}", pert, domains[i % len(domains)]))
+        keys = jax.random.split(jax.random.fold_in(rng, i + 1), len(leaves))
+        host = [np.asarray(perturb(x, k))
+                if jnp.issubdtype(x.dtype, jnp.floating) else np.asarray(x)
+                for x, k in zip(leaves, keys)]
+        out.append((f"expert{i:03d}", jax.tree.unflatten(treedef, host),
+                    domains[i % len(domains)]))
     return out, nbytes
 
 
@@ -312,14 +319,16 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     if args.node_shape:
-        # the emulated-socket flag must land before the backend initializes
-        from repro.node.topology import ensure_emulated_sockets
+        # the CPU socket count must land before the backend initializes
+        from repro.node.topology import ensure_node_devices
         tp, n_groups = (int(x) for x in args.node_shape.split("x"))
-        ensure_emulated_sockets(tp * n_groups)
+        ensure_node_devices(tp * n_groups)
 
     from repro.configs import get_config, pad_for_tp, reduced
+    from repro.launch.compile_cache import setup_compile_cache
     from repro.obs import get_registry, serve_metrics, trace
 
+    print(f"compile cache: {setup_compile_cache()}")
     install_flight_dump_signal(args.flight_out)
 
     # the engine/node is built after the httpd starts; /readyz reads the

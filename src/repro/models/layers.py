@@ -390,8 +390,7 @@ def moe_apply_ep_local(cfg: ModelConfig, p, x, mesh):
         out = jax.lax.psum(out, "model")                   # the only collective
         return out.astype(xt.dtype).reshape(Bl, Sl, Dl)
 
-    from repro.distributed.ctx import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec(dp if dp else None, None, None),
                   jax.sharding.PartitionSpec(None, None),

@@ -37,7 +37,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.distributed import partitioning as part
-from repro.distributed.ctx import shard_map
 from repro.obs import trace
 from repro.serving.engine import PagedDecodeRunner, ServingEngine
 from repro.serving.prefill import record_compile
@@ -212,7 +211,6 @@ def _tp_fused_paged_extend(cfg: ModelConfig, tp: int, vocab_sharded: bool,
         mp = lp["mlp"]
         y = ffn_swiglu(hh, lp["mlp_norm"]["scale"], mp["wi_gate"],
                        mp["wi_up"], mp["wo"], residual=False,
-                       block_f=math.gcd(mp["wi_gate"].shape[1], 512),
                        interpret=it)                              # partial
         hh = hh + jax.lax.psum(y, "model")                        # reduce #2
         return hh, (kp, vp)
@@ -296,7 +294,7 @@ class TPPagedDecodeRunner(PagedDecodeRunner):
         if key not in self._extend:
             record_compile("tp_extend")
             logits_spec = P(None, None, "model") if self.vocab_sharded else P()
-            mapped = shard_map(
+            mapped = jax.shard_map(
                 self._tp_body(key[1]),
                 mesh=self.mesh,
                 in_specs=(self.param_pspecs, self.pool_pspec, self.pool_pspec,

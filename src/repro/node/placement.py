@@ -27,7 +27,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 from repro.core.bandwidth_model import expert_service_cost
-from repro.core.memory_tiers import MachineTiers, TPU_V5E_NODE
+from repro.core.memory_tiers import MachineTiers, machine_for
 
 
 @dataclass(frozen=True)
@@ -56,15 +56,19 @@ class Placement:
 
 def plan_expert_placement(profiles: Sequence[ExpertProfile],
                           group_weight_budgets: Sequence[int], *,
-                          machine: MachineTiers = TPU_V5E_NODE,
+                          machine: Optional[MachineTiers] = None,
                           tp: int = 1, avg_tokens: int = 16,
                           replicate_share: float = 0.5) -> Placement:
     """Greedy bandwidth-balanced assignment of experts to socket groups.
 
     ``group_weight_budgets[g]`` is group g's HBM weights share in bytes
     (``coe.hbm_budget.weights_bytes``). Returns a :class:`Placement` whose
-    per-group resident bytes never exceed the budgets.
+    per-group resident bytes never exceed the budgets. ``machine`` defaults
+    to the preset for this process's devices.
     """
+    if machine is None:
+        import jax
+        machine = machine_for(jax.devices())
     n_groups = len(group_weight_budgets)
     if n_groups < 1:
         raise ValueError("need at least one socket group")

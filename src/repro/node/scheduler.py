@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.coe import CompositionOfExperts, ExpertHandle
-from repro.core.memory_tiers import MachineTiers, TPU_V5E_NODE
+from repro.core.memory_tiers import MachineTiers, machine_for
 from repro.node.execution import PrefillWorker, make_group_engine
 from repro.node.placement import (ExpertProfile, Placement,
                                   plan_expert_placement)
@@ -105,7 +105,7 @@ class RDUNode:
                  router_params=None, *,
                  group_hbm_bytes: int, group_kv_reserve_bytes: int = 0,
                  store: Optional[ExpertStore] = None,
-                 machine: MachineTiers = TPU_V5E_NODE,
+                 machine: Optional[MachineTiers] = None,
                  avg_tokens: int = 16, replicate_share: float = 0.5,
                  registry: Optional[MetricsRegistry] = None,
                  prefill_groups: int = 0, prefill_pack: Optional[int] = None,
@@ -124,7 +124,8 @@ class RDUNode:
         their KV blocks are handed off to a decode group's cache
         (``Request.handoff``) — decode engines never run a prefill forward.
         ``prefill_pack`` caps prompts per packed prefill call (default: the
-        engines' ``n_slots``)."""
+        engines' ``n_slots``). ``machine`` defaults to the preset for the
+        topology's devices (``core.memory_tiers.machine_for``)."""
         if not 0 <= prefill_groups < len(topology.groups):
             raise ValueError(
                 f"prefill_groups={prefill_groups} must leave at least one "
@@ -134,7 +135,8 @@ class RDUNode:
         self.router = router
         self.router_params = router_params
         self.store = store if store is not None else HostMemoryStore()
-        self.machine = machine
+        self.machine = machine or machine_for(
+            topology.groups[0].devices)
         self.avg_tokens = avg_tokens
         self.replicate_share = replicate_share
         # one node-wide registry: every group's engine/cache/ledger series

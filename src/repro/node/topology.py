@@ -9,13 +9,12 @@ eight TP=1 groups (``1x8``), and so on. Each group gets its own one-axis
 ``('model',)`` JAX mesh over a disjoint device subset — the inter-RDU TP
 domain — while the shared ``ExpertStore`` plays the node-wide DDR tier.
 
-Emulation: run under ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-to get 8 CPU "sockets" on one host.
+On a TPU host the groups are carved from the real chips. On the CPU,
+``ensure_node_devices`` (or ``XLA_FLAGS=--xla_force_host_platform_device_count=8``)
+emulates 8 "sockets" on one host.
 """
 from __future__ import annotations
 
-import os
-import re
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
@@ -25,29 +24,29 @@ from jax.sharding import Mesh
 from repro.launch.mesh import make_device_mesh
 
 
-def ensure_emulated_sockets(n: int):
-    """Make ``n`` emulated CPU sockets visible. The
-    ``--xla_force_host_platform_device_count`` flag only works before the
-    JAX backend initializes, so call this before anything touches devices;
-    if the backend beat us to it, fail with the exact flag to relaunch
-    with. Node drivers (``launch/serve.py --node-shape``,
-    ``benchmarks/run.py --sweep-node``) share this bootstrap."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    flag_re = r"--xla_force_host_platform_device_count=(\d+)"
-    m = re.search(flag_re, flags)
-    if m is None:
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={n}".strip())
-    elif int(m.group(1)) < n:
-        # a stale smaller count (e.g. exported by an earlier run) still
-        # works if the backend has not initialized yet — raise it in place
-        os.environ["XLA_FLAGS"] = re.sub(
-            flag_re, f"--xla_force_host_platform_device_count={n}", flags)
-    if len(jax.devices()) < n:
+def ensure_node_devices(n: int):
+    """Make ``n`` devices visible for a node shape, before anything touches
+    the JAX backend. On the CPU backend the sockets are emulated
+    (``jax_num_cpu_devices``, which only the CPU client reads); on an
+    accelerator the node runs on the real chips, and a shape that needs more
+    than are present fails with the count. Node drivers
+    (``launch/serve.py --node-shape``, ``benchmarks/run.py --sweep-node``)
+    share this bootstrap."""
+    try:
+        jax.config.update("jax_num_cpu_devices", n)
+    except RuntimeError:
+        pass            # backend already initialized: the count check decides
+    devs = jax.devices()
+    if len(devs) >= n:
+        return
+    if devs[0].platform == "cpu":
         raise SystemExit(
             f"{n} emulated sockets requested but the JAX backend already "
-            f"initialized with {len(jax.devices())} device(s); launch with "
+            f"initialized with {len(devs)} device(s); launch with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n}")
+    raise SystemExit(
+        f"node shape needs {n} chips but {len(devs)} {devs[0].platform} "
+        f"device(s) ({devs[0].device_kind}) are present")
 
 
 @dataclass(frozen=True)

@@ -141,8 +141,6 @@ def fused_paged_extend(cfg: ModelConfig, params, pk, pv, tables, lengths,
     block = pk.shape[2]
     maxb = tables.shape[1]
     Hq, dh, D = cfg.n_heads, cfg.head_dim, cfg.d_model
-    F = cfg.d_ff
-    bf = math.gcd(F, 512)              # largest MXU-friendly divisor of F
     it = resolve_interpret(interpret)
 
     h = T.embed_tokens(cfg, params, tokens)[:, 0]                 # (B, D)
@@ -166,7 +164,7 @@ def fused_paged_extend(cfg: ModelConfig, params, pk, pv, tables, lengths,
                               p["wo"].reshape(Hq * dh, D),
                               lp["mlp_norm"]["scale"], lp["mlp"]["wi_gate"],
                               lp["mlp"]["wi_up"], lp["mlp"]["wo"],
-                              block_f=bf, interpret=it)
+                              interpret=it)
         return hh, (kp, vp)
 
     h, (pk, pv) = jax.lax.scan(body, h, (params["layers"], pk, pv))
@@ -351,6 +349,16 @@ class PagedDecodeRunner:
                         backend=self.backend.name, batch=key[0], g=key[1]):
             return fn(*args)
 
+    def compiled_extend(self, key=None):
+        """The compiled extend step for ``key`` (a ``tokens.shape``; default
+        the most recent), lowered against the shapes it last ran with, or
+        None when it never ran. With the persistent compile cache on, this
+        re-reads the executable rather than compiling it again."""
+        key = key or self._last_key
+        if key is None or key not in self._abstract:
+            return None
+        return self._extend_jit(key).lower(*self._abstract[key]).compile()
+
     def step_cost_analysis(self, key=None) -> Optional[dict]:
         """XLA cost analysis ('bytes accessed', 'flops', ...) of a compiled
         extend step — the measured side of the Fig-6 sweep. ``key`` is a
@@ -358,13 +366,9 @@ class PagedDecodeRunner:
         step never ran or the backend offers no cost model. NOTE: Pallas
         kernels appear as opaque custom calls to XLA's model — add
         ``FusedPagedBackend.kernel_hbm_bytes`` for their traffic."""
-        key = key or self._last_key
-        if key is None or key not in self._abstract:
-            return None
         try:
-            compiled = self._extend_jit(key).lower(
-                *self._abstract[key]).compile()
-            cost = compiled.cost_analysis()
+            compiled = self.compiled_extend(key)
+            cost = compiled.cost_analysis() if compiled else None
         except Exception:
             return None
         if isinstance(cost, (list, tuple)):

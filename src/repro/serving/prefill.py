@@ -13,9 +13,10 @@ This module removes the hazard and amortizes the per-admit forward:
   * **packing via segment ids**: several prompts ride in ONE ``(1, bucket)``
     call. The causal mask is blocked across segments
     (``(seg_i == seg_j) & (j <= i)``) and RoPE positions restart per
-    segment, so each prompt's logits and K/V are *bit-identical* to its own
-    sequential ``prefill_kv`` call (masked cross-segment scores contribute
-    exact zeros; verified at f32 and bf16 by tests/test_prefill.py).
+    segment, so each prompt's logits and K/V equal its own sequential
+    ``prefill_kv`` call up to summation order (masked cross-segment scores
+    contribute exact zeros; tests/test_prefill.py holds them to
+    ``models.reference.F32_TOL`` at f32).
     Padding gets its own segment id — pad queries attend at least
     themselves, so no softmax row is fully masked and no NaN can leak
     through ``0 * NaN`` into real rows;
@@ -306,6 +307,10 @@ class PackedPrefillRunner:
             rows = jnp.full((b,), scratch, jnp.int32)
             offs = jnp.zeros((b,), jnp.int32)
             self._ensure_scatter(b, (pool.k, pool.v, k, v, rows, offs))
+
+    def compiled_forward(self, bucket: int) -> jax.stages.Compiled:
+        """The AOT-compiled forward of one warmed (or already run) bucket."""
+        return self._fwd[bucket]
 
     # -- execution --------------------------------------------------------
     def pack(self, prompts: Sequence[np.ndarray]):

@@ -9,22 +9,6 @@ import pytest
 
 jax.config.update("jax_enable_x64", False)
 
-# Property tests prefer the real hypothesis; fall back to the deterministic
-# sampling stub in tests/_hypothesis_stub.py when it is not installed.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import importlib.util
-    import pathlib
-    import sys
-
-    _stub_path = pathlib.Path(__file__).parent / "_hypothesis_stub.py"
-    _spec = importlib.util.spec_from_file_location("hypothesis", _stub_path)
-    _mod = importlib.util.module_from_spec(_spec)
-    sys.modules["hypothesis"] = _mod
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis.strategies"] = _mod.strategies
-
 
 @pytest.fixture(scope="session")
 def rng():

@@ -123,6 +123,26 @@ def test_oversized_expert_raises():
         cache.activate("big")
 
 
+def test_miss_evicts_before_h2d():
+    """An inline miss makes room BEFORE its H2D copy: at the moment the
+    incoming expert lands on the device, the resident bytes plus the
+    incoming bytes fit the tier (never capacity plus one expert)."""
+    cache = HBMWeightCache(2 * 1024, fetch=lambda n: _mk_host())
+    put = cache._put_device
+    peaks = []
+
+    def watched_put(host):
+        peaks.append(cache.used_bytes + sum(x.nbytes for x in
+                                            jax.tree.leaves(host)))
+        return put(host)
+
+    cache._put_device = watched_put
+    for name in ["a", "b", "c", "a", "d"]:
+        cache.activate(name)
+    assert cache.stats.misses == 5 and cache.stats.evictions == 3
+    assert max(peaks) <= cache.capacity, peaks
+
+
 @given(st.lists(st.integers(0, 5), min_size=1, max_size=40))
 @settings(max_examples=30, deadline=None)
 def test_lru_capacity_invariant(seq):

@@ -153,13 +153,17 @@ def test_qkv_rope_paged_matches_layer_reference(cfg):
                                    rtol=2e-5, atol=2e-5)
 
 
-def test_oproj_ffn_swiglu_matches_reference(cfg):
+@pytest.mark.parametrize("wide", [False, True])
+def test_oproj_ffn_swiglu_matches_reference(cfg, wide):
     """Whole layer epilogue (out-proj + residual + norm + SwiGLU + residual)
     against the explicit composition, with a non-default block_f so the
-    FFN grid actually iterates."""
+    FFN grid actually iterates. ``wide`` makes d_model large enough that Wo
+    streams in several row tiles, as at published widths."""
     from repro.kernels.fused_decode.kernel import oproj_ffn_swiglu
 
     B, D, F, HD = 4, cfg.d_model, cfg.d_ff, cfg.n_heads * cfg.head_dim
+    if wide:
+        D, HD = 2048, 256                    # f32 Wo tiles of 128 rows
     rs = np.random.RandomState(3)
     x = jnp.asarray(rs.standard_normal((B, D)), jnp.float32)
     attn = jnp.asarray(rs.standard_normal((B, HD)), jnp.float32)
