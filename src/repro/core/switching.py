@@ -239,8 +239,9 @@ class HBMWeightCache:
 
     def _evict_one(self):
         name, entry = self._entries.popitem(last=False)     # LRU = oldest
-        self.stats.evictions += 1
-        self._retire(name, entry)
+        with trace.span("evict", cat="switch", expert=name):
+            self.stats.evictions += 1
+            self._retire(name, entry)
 
     def _make_room(self, need: int, *, strict: bool = True) -> bool:
         """Evict until ``need`` bytes fit inside the capacity NOT already
@@ -317,54 +318,56 @@ class HBMWeightCache:
             self.stats.hits += 1
             return self._entries[expert_id].value
         t0 = time.perf_counter()
-        sp = trace.span("activate", cat="switch", expert=expert_id)
-        sp.__enter__()
-        fut = self._inflight.pop(expert_id, None)
-        consumed_prefetch = False
-        failed_wait_s = 0.0          # time sunk into a prefetch that raised
-        loaded = None
-        if fut is not None:
-            self._unreserve(expert_id)
-            try:
-                loaded = fut.result()
-                consumed_prefetch = True
-                self.stats.hits += 1
-                self.stats.prefetch_hits += 1
-            except Exception:
-                # failed prefetch load: retry as a miss — but the wait on
-                # the doomed future is its own stall cause, not miss time
-                # (previously folded into the miss bucket, hiding the
-                # wasted prefetch-issue cost)
-                failed_wait_s = time.perf_counter() - t0
-                self.stats.prefetch_failures += 1
-                self.stats.stall_failed_prefetch_seconds += failed_wait_s
-                self.ledger.note_stall(failed_wait_s, cause="failed_prefetch")
-                trace.instant("prefetch_failed", cat="switch",
-                              expert=expert_id)
-        if loaded is None:
-            # true miss: load inline on the caller thread — submitting to
-            # the (max_inflight-sized) executor would queue the critical
-            # path behind in-flight prefetches of OTHER experts. Room is
-            # made between the store read and the H2D copy, so HBM never
-            # holds the tier's capacity plus the incoming expert
-            self.stats.misses += 1
-            host, read_s = self._read(expert_id)
-            self._make_room(tree_bytes(host))
-            loaded = self._h2d(expert_id, host, read_s)
-        cause = "prefetch" if consumed_prefetch else (
-            "failed_prefetch" if failed_wait_s else "miss")
-        value = self._finish_load(expert_id, loaded, read_only, cause=cause)
-        dt = time.perf_counter() - t0
-        self.stats.switch_seconds += dt
-        if consumed_prefetch:
-            self.stats.stall_prefetch_seconds += dt
-            self.ledger.note_stall(dt, cause="prefetch")
-        else:
-            miss_dt = dt - failed_wait_s
-            self.stats.stall_miss_seconds += miss_dt
-            self.ledger.note_stall(miss_dt, cause="miss")
-        sp.add(outcome=cause, nbytes=loaded.nbytes)
-        sp.__exit__(None, None, None)
+        with trace.span("activate", cat="switch", expert=expert_id) as sp:
+            fut = self._inflight.pop(expert_id, None)
+            consumed_prefetch = False
+            failed_wait_s = 0.0      # time sunk into a prefetch that raised
+            loaded = None
+            if fut is not None:
+                self._unreserve(expert_id)
+                try:
+                    with trace.span("prefetch_wait", cat="switch",
+                                    expert=expert_id):
+                        loaded = fut.result()
+                    consumed_prefetch = True
+                    self.stats.hits += 1
+                    self.stats.prefetch_hits += 1
+                except Exception:
+                    # failed prefetch load: retry as a miss — but the wait
+                    # on the doomed future is its own stall cause, not miss
+                    # time
+                    failed_wait_s = time.perf_counter() - t0
+                    self.stats.prefetch_failures += 1
+                    self.stats.stall_failed_prefetch_seconds += failed_wait_s
+                    self.ledger.note_stall(failed_wait_s,
+                                           cause="failed_prefetch")
+                    trace.instant("prefetch_failed", cat="switch",
+                                  expert=expert_id)
+            if loaded is None:
+                # true miss: load inline on the caller thread — submitting
+                # to the (max_inflight-sized) executor would queue the
+                # critical path behind in-flight prefetches of OTHER
+                # experts. Room is made between the store read and the H2D
+                # copy, so HBM never holds the tier's capacity plus the
+                # incoming expert
+                self.stats.misses += 1
+                host, read_s = self._read(expert_id)
+                self._make_room(tree_bytes(host))
+                loaded = self._h2d(expert_id, host, read_s)
+            cause = "prefetch" if consumed_prefetch else (
+                "failed_prefetch" if failed_wait_s else "miss")
+            value = self._finish_load(expert_id, loaded, read_only,
+                                      cause=cause)
+            dt = time.perf_counter() - t0
+            self.stats.switch_seconds += dt
+            if consumed_prefetch:
+                self.stats.stall_prefetch_seconds += dt
+                self.ledger.note_stall(dt, cause="prefetch")
+            else:
+                miss_dt = dt - failed_wait_s
+                self.stats.stall_miss_seconds += miss_dt
+                self.ledger.note_stall(miss_dt, cause="miss")
+            sp.add(outcome=cause, nbytes=loaded.nbytes)
         return value
 
     def prefetch(self, expert_id: str, *, read_only: bool = True) -> bool:

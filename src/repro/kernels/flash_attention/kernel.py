@@ -101,6 +101,7 @@ def flash_prefill(q, k, v, *, causal=True, window=0, block_q=128, block_k=128,
         out_specs=pl.BlockSpec((1, 1, bq, dh), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_prefill",
     )(q, k, v)
 
 
@@ -246,6 +247,7 @@ def flash_decode_paged(q, k_pool, v_pool, tables, len1, *, interpret=False):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, G, Hkv, dh), q.dtype),
         interpret=interpret,
+        name="decode_paged",
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(len1, jnp.int32),
       q, k_pool, v_pool)
 
@@ -280,4 +282,5 @@ def flash_decode(q, k_cache, v_cache, length, *, block_k=512, interpret=False):
             pltpu.VMEM((Hq,), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode",
     )(length, q, k_cache, v_cache)

@@ -96,6 +96,7 @@ def qkv_rope(x, norm_scale, w_qkv, pos, *, n_q, n_kv, dh, theta=10000.0,
         out_specs=pl.BlockSpec((1, B, dh), lambda h: (h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((H, B, dh), x.dtype),
         interpret=interpret,
+        name="qkv_rope",
     )(jnp.asarray(pos, jnp.int32).reshape(1), x, norm_scale,
       w_qkv.reshape(D, H, dh))
 
@@ -204,6 +205,7 @@ def qkv_rope_paged(x, norm_scale, wq, wk, wv, pos, *, theta=10000.0,
         out_specs=pl.BlockSpec((1, B, dh), lambda h: (h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((H, B, dh), x.dtype),
         interpret=interpret,
+        name="qkv_rope_paged",
     )(jnp.asarray(pos, jnp.int32).reshape(B, 1), x, norm_scale.reshape(1, D),
       jnp.asarray(inv_full), jnp.asarray(sign), wq.reshape(D, n_q * dh),
       wk.reshape(D, n_kv * dh), wv.reshape(D, n_kv * dh))
@@ -268,6 +270,7 @@ def ffn_swiglu(x, norm_scale, w_gate, w_up, w_down, *, block_f=None,
         out_shape=jax.ShapeDtypeStruct((B, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((B, D), jnp.float32)],
         interpret=interpret,
+        name="ffn_swiglu",
     )(x, norm_scale.reshape(1, D), w_gate, w_up, w_down)
 
 
@@ -361,4 +364,5 @@ def oproj_ffn_swiglu(x, attn_out, w_o, norm_scale, w_gate, w_up, w_down, *,
             pltpu.VMEM((B, D), jnp.float32),
         ],
         interpret=interpret,
+        name="oproj_ffn_swiglu",
     )(x, attn_out, w_o, norm_scale.reshape(1, D), w_gate, w_up, w_down)

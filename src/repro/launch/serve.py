@@ -172,7 +172,7 @@ def _serve_single(args, cfg, server=None, ready=None):
     wall = time.perf_counter() - t0
     st = engine.stats
     print(f"served {len(done)} requests in {wall:.2f}s "
-          f"({st.tokens_out} tokens, {st.tokens_per_second:.1f} tok/s); "
+          f"({st.tokens_out} tokens, {st.tokens_out / wall:.1f} tok/s); "
           f"{n_tagged} caller-tagged, {len(done) - n_tagged} router-routed")
     print(f"breakdown: route={st.route_s:.3f}s switch={st.switch_s:.3f}s "
           f"prefill={st.prefill_s:.3f}s decode={st.exec_s:.3f}s "

@@ -279,12 +279,18 @@ class TPPagedDecodeRunner(PagedDecodeRunner):
         single-device ``FusedPagedBackend`` dispatch)."""
         cfg, scratch = self.cfg, self.scratch_row
         tp, kvs, vs = self.tp, self.kv_sharded, self.vocab_sharded
+        # named like the single-device step: the program is ``decode_step``
         if self.backend.name == "fused" and g == 1:
             it = self.backend.interpret
-            return lambda p, k, v, tb, ln, ac, tk: _tp_fused_paged_extend(
-                cfg, tp, vs, p, k, v, tb, ln, ac, tk, scratch, interpret=it)
-        return lambda p, k, v, tb, ln, ac, tk: _tp_paged_extend(
-            cfg, tp, kvs, vs, p, k, v, tb, ln, ac, tk, scratch)
+
+            def decode_step(p, k, v, tb, ln, ac, tk):
+                return _tp_fused_paged_extend(cfg, tp, vs, p, k, v, tb, ln,
+                                              ac, tk, scratch, interpret=it)
+        else:
+            def decode_step(p, k, v, tb, ln, ac, tk):
+                return _tp_paged_extend(cfg, tp, kvs, vs, p, k, v, tb, ln,
+                                        ac, tk, scratch)
+        return decode_step
 
     def extend(self, params, pk, pv, tables, lengths, active, tokens):
         if self.tp == 1:

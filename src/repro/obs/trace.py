@@ -19,6 +19,14 @@ Tracing is OFF by default and the disabled path is allocation-free:
 trace calls on the per-step decode hot path (asserted by
 ``tests/test_obs.py``).
 
+Spans are also the program's spans on the device's clock: while a
+``jax.profiler`` session records (``TraceAnnotation.is_enabled()``), every
+``span()`` enters a ``TraceAnnotation`` named ``<cat>.<name>``
+(``engine.decode``, ``switch.activate``), so the profile places each span
+on its thread beside the device's operations. That holds with the ring
+tracer on or off; the ring records only while it is enabled. Instants and
+async events stay in the ring.
+
 ``export(path)`` writes the Chrome trace-event format that Perfetto
 (https://ui.perfetto.dev) and ``chrome://tracing`` open directly:
 ``{"traceEvents": [{"name", "ph", "ts", "dur", "pid", "tid", "args"}]}``
@@ -34,34 +42,46 @@ from collections import deque
 from pathlib import Path
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
+_profiling = TraceAnnotation.is_enabled
+
 
 class Span:
-    """A live span: ``with`` records one complete ("X") event on exit.
+    """A live span: ``with`` records one complete ("X") event on exit when
+    the ring tracer is on (``tracer``), and spans a profiler
+    ``TraceAnnotation`` when a profiler session records (``profiled``).
     ``add(**attrs)`` attaches attrs discovered mid-span (outcome, bytes)."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annot")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Dict[str, Any]):
+    def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
+                 args: Dict[str, Any], profiled: bool = False):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._annot = TraceAnnotation(f"{cat}.{name}") if profiled else None
 
     def add(self, **attrs):
         self.args.update(attrs)
         return self
 
     def __enter__(self):
+        if self._annot is not None:
+            self._annot.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         tr = self._tracer
-        tr._record({"name": self.name, "cat": self.cat, "ph": "X",
-                    "ts": tr._us(self._t0), "dur": (t1 - self._t0) * 1e6,
-                    "args": self.args})
+        if tr is not None:
+            tr._record({"name": self.name, "cat": self.cat, "ph": "X",
+                        "ts": tr._us(self._t0), "dur": (t1 - self._t0) * 1e6,
+                        "args": self.args})
+        if self._annot is not None:
+            self._annot.__exit__(*exc)
         return False
 
 
@@ -145,9 +165,10 @@ class Tracer:
         self._ring().append(ev)
 
     def span(self, name: str, cat: str = "repro", **attrs):
-        if not self.enabled:
+        if not self.enabled and not _profiling():
             return NOOP_SPAN
-        return Span(self, name, cat, attrs)
+        return Span(self if self.enabled else None, name, cat, attrs,
+                    _profiling())
 
     def instant(self, name: str, cat: str = "repro", **attrs):
         if not self.enabled:
@@ -253,10 +274,12 @@ def disable():
 
 
 def span(name: str, cat: str = "repro", **attrs):
+    """A span on the default tracer's ring and, while a profiler session
+    records, on the profile (see the module docstring)."""
     t = _tracer
-    if not t.enabled:
+    if not t.enabled and not _profiling():
         return NOOP_SPAN
-    return Span(t, name, cat, attrs)
+    return Span(t if t.enabled else None, name, cat, attrs, _profiling())
 
 
 def instant(name: str, cat: str = "repro", **attrs):

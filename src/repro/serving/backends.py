@@ -27,7 +27,12 @@ threaded surfaces: ``ServingEngine(backend=)``, ``RDUNode(backend=)`` /
 
 Every compiled step is wrapped in a ``decode_kernel`` trace span (labelled
 with the backend) and exposes ``step_cost_analysis()`` — the measured
-HBM-traffic side of the Fig-6 fused-vs-unfused sweep.
+HBM-traffic side of the Fig-6 fused-vs-unfused sweep. On the device the
+step is the program ``decode_step``, and both bodies name the same parts
+with ``jax.named_scope``: ``embed``, then per layer (the scan body)
+``qkv_rope``, ``kv_scatter``, ``attention`` and ``oproj_ffn``, then
+``head``. A profile attributes each device operation by program, then by
+the innermost of these scopes in its ``op_name``.
 """
 from __future__ import annotations
 
@@ -67,7 +72,8 @@ def xla_paged_extend(cfg: ModelConfig, params, pk, pv, tables, lengths,
     block = pk.shape[2]
     maxb = tables.shape[1]
     S = maxb * block
-    h = T.embed_tokens(cfg, params, tokens)                       # (B,g,D)
+    with jax.named_scope("embed"):
+        h = T.embed_tokens(cfg, params, tokens)                   # (B,g,D)
     positions = lengths[:, None] + jnp.arange(g, dtype=jnp.int32)[None]
     blk_idx = jnp.minimum(positions // block, maxb - 1)
     rows = jnp.take_along_axis(tables, blk_idx, axis=1)           # (B,g)
@@ -81,37 +87,42 @@ def xla_paged_extend(cfg: ModelConfig, params, pk, pv, tables, lengths,
     def body(hh, xs):
         lp, kp, vp = xs                    # kp (rows, block, Hkv, dh)
         p = lp["attn"]
-        hn = L.apply_norm(cfg, p["norm"], hh)
-        q = jnp.einsum("bsd,dhk->bshk", hn, p["wq"])
-        k = jnp.einsum("bsd,dhk->bshk", hn, p["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", hn, p["wv"])
-        if cfg.qkv_bias:
-            q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-        q = L.apply_rope(cfg, q, positions)
-        k = L.apply_rope(cfg, k, positions)
-        kp = kp.at[rows, off].set(k.astype(kp.dtype))
-        vp = vp.at[rows, off].set(v.astype(vp.dtype))
-        kc = kp[tables].reshape(B, S, *kp.shape[2:])              # (B,S,Hkv,dh)
-        vc = vp[tables].reshape(B, S, *vp.shape[2:])
-        Hkv = kc.shape[2]
-        qg = q.reshape(B, g, Hkv, Hq // Hkv, dh)
-        s = jnp.einsum("bqhgd,bshd->bhgqs", qg, kc,
-                       preferred_element_type=jnp.float32) / math.sqrt(dh)
-        s = jnp.where(mask[:, None, None], s, -jnp.inf)
-        pa = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhgqs,bshd->bqhgd", pa.astype(vc.dtype), vc,
-                       preferred_element_type=jnp.float32)
-        o = o.reshape(B, g, Hq, dh).astype(hh.dtype)
-        y = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-        if cfg.attn_out_bias:
-            y = y + p["bo"]
-        hh = hh + y
-        hh = T._mlp(cfg, lp["mlp_norm"], lp["mlp"], hh, moe)
+        with jax.named_scope("qkv_rope"):
+            hn = L.apply_norm(cfg, p["norm"], hh)
+            q = jnp.einsum("bsd,dhk->bshk", hn, p["wq"])
+            k = jnp.einsum("bsd,dhk->bshk", hn, p["wk"])
+            v = jnp.einsum("bsd,dhk->bshk", hn, p["wv"])
+            if cfg.qkv_bias:
+                q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+            q = L.apply_rope(cfg, q, positions)
+            k = L.apply_rope(cfg, k, positions)
+        with jax.named_scope("kv_scatter"):
+            kp = kp.at[rows, off].set(k.astype(kp.dtype))
+            vp = vp.at[rows, off].set(v.astype(vp.dtype))
+        with jax.named_scope("attention"):
+            kc = kp[tables].reshape(B, S, *kp.shape[2:])          # (B,S,Hkv,dh)
+            vc = vp[tables].reshape(B, S, *vp.shape[2:])
+            Hkv = kc.shape[2]
+            qg = q.reshape(B, g, Hkv, Hq // Hkv, dh)
+            s = jnp.einsum("bqhgd,bshd->bhgqs", qg, kc,
+                           preferred_element_type=jnp.float32) / math.sqrt(dh)
+            s = jnp.where(mask[:, None, None], s, -jnp.inf)
+            pa = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bhgqs,bshd->bqhgd", pa.astype(vc.dtype), vc,
+                           preferred_element_type=jnp.float32)
+            o = o.reshape(B, g, Hq, dh).astype(hh.dtype)
+        with jax.named_scope("oproj_ffn"):
+            y = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+            if cfg.attn_out_bias:
+                y = y + p["bo"]
+            hh = hh + y
+            hh = T._mlp(cfg, lp["mlp_norm"], lp["mlp"], hh, moe)
         return hh, (kp, vp)
 
     h, (pk, pv) = jax.lax.scan(body, h, (params["layers"], pk, pv))
-    h = L.apply_norm(cfg, params["final_norm"], h)
-    logits = T.unembed(cfg, params, h)
+    with jax.named_scope("head"):
+        h = L.apply_norm(cfg, params["final_norm"], h)
+        logits = T.unembed(cfg, params, h)
     return logits, pk, pv
 
 
@@ -143,7 +154,8 @@ def fused_paged_extend(cfg: ModelConfig, params, pk, pv, tables, lengths,
     Hq, dh, D = cfg.n_heads, cfg.head_dim, cfg.d_model
     it = resolve_interpret(interpret)
 
-    h = T.embed_tokens(cfg, params, tokens)[:, 0]                 # (B, D)
+    with jax.named_scope("embed"):
+        h = T.embed_tokens(cfg, params, tokens)[:, 0]             # (B, D)
     pos = lengths                                                 # (B,)
     blk_idx = jnp.minimum(pos // block, maxb - 1)
     rows = jnp.take_along_axis(tables, blk_idx[:, None], axis=1)[:, 0]
@@ -154,22 +166,28 @@ def fused_paged_extend(cfg: ModelConfig, params, pk, pv, tables, lengths,
     def body(hh, xs):
         lp, kp, vp = xs                    # kp (rows, block, Hkv, dh)
         p = lp["attn"]
-        q, k, v = qkv_rope_paged(hh, p["norm"]["scale"], p["wq"], p["wk"],
-                                 p["wv"], pos, theta=cfg.rope_theta,
-                                 interpret=it)
-        kp = kp.at[rows, off].set(k.astype(kp.dtype))
-        vp = vp.at[rows, off].set(v.astype(vp.dtype))
-        o = decode_paged(q, kp, vp, tables, len1, interpret=it)   # (B,Hq,dh)
-        hh = oproj_ffn_swiglu(hh, o.reshape(B, Hq * dh),
-                              p["wo"].reshape(Hq * dh, D),
-                              lp["mlp_norm"]["scale"], lp["mlp"]["wi_gate"],
-                              lp["mlp"]["wi_up"], lp["mlp"]["wo"],
-                              interpret=it)
+        with jax.named_scope("qkv_rope"):
+            q, k, v = qkv_rope_paged(hh, p["norm"]["scale"], p["wq"],
+                                     p["wk"], p["wv"], pos,
+                                     theta=cfg.rope_theta, interpret=it)
+        with jax.named_scope("kv_scatter"):
+            kp = kp.at[rows, off].set(k.astype(kp.dtype))
+            vp = vp.at[rows, off].set(v.astype(vp.dtype))
+        with jax.named_scope("attention"):
+            o = decode_paged(q, kp, vp, tables, len1,
+                             interpret=it)                        # (B,Hq,dh)
+        with jax.named_scope("oproj_ffn"):
+            hh = oproj_ffn_swiglu(hh, o.reshape(B, Hq * dh),
+                                  p["wo"].reshape(Hq * dh, D),
+                                  lp["mlp_norm"]["scale"],
+                                  lp["mlp"]["wi_gate"], lp["mlp"]["wi_up"],
+                                  lp["mlp"]["wo"], interpret=it)
         return hh, (kp, vp)
 
     h, (pk, pv) = jax.lax.scan(body, h, (params["layers"], pk, pv))
-    h = L.apply_norm(cfg, params["final_norm"], h)[:, None]       # (B,1,D)
-    logits = T.unembed(cfg, params, h)
+    with jax.named_scope("head"):
+        h = L.apply_norm(cfg, params["final_norm"], h)[:, None]   # (B,1,D)
+        logits = T.unembed(cfg, params, h)
     return logits, pk, pv
 
 
@@ -206,6 +224,14 @@ def fused_kernel_hbm_bytes(cfg: ModelConfig, batch: int, maxb: int,
 # Backend objects + the runner
 # ----------------------------------------------------------------------
 
+def _decode_step(body, cfg: ModelConfig, scratch_row: int, **kw):
+    """``body`` bound to one config as the function the runner jits: its
+    name, ``decode_step``, names the program on the device."""
+    def decode_step(p, pk, pv, tb, ln, ac, tk):
+        return body(cfg, p, pk, pv, tb, ln, ac, tk, scratch_row, **kw)
+    return decode_step
+
+
 class PagedBackend:
     """One way to execute the paged extend step. Subclasses supply
     ``extend_fn(B, g)`` -> a traceable ``f(params, pk, pv, tables, lengths,
@@ -227,9 +253,7 @@ class XlaPagedBackend(PagedBackend):
     name = "xla"
 
     def extend_fn(self, batch: int, g: int):
-        cfg, scratch = self.cfg, self.scratch_row
-        return lambda p, pk, pv, tb, ln, ac, tk: xla_paged_extend(
-            cfg, p, pk, pv, tb, ln, ac, tk, scratch)
+        return _decode_step(xla_paged_extend, self.cfg, self.scratch_row)
 
 
 class FusedPagedBackend(PagedBackend):
@@ -260,12 +284,10 @@ class FusedPagedBackend(PagedBackend):
                 f"{', '.join(unsupported)} — use backend='xla'")
 
     def extend_fn(self, batch: int, g: int):
-        cfg, scratch, it = self.cfg, self.scratch_row, self.interpret
         if g > 1:
-            return lambda p, pk, pv, tb, ln, ac, tk: xla_paged_extend(
-                cfg, p, pk, pv, tb, ln, ac, tk, scratch)
-        return lambda p, pk, pv, tb, ln, ac, tk: fused_paged_extend(
-            cfg, p, pk, pv, tb, ln, ac, tk, scratch, interpret=it)
+            return _decode_step(xla_paged_extend, self.cfg, self.scratch_row)
+        return _decode_step(fused_paged_extend, self.cfg, self.scratch_row,
+                            interpret=self.interpret)
 
     def kernel_hbm_bytes(self, batch: int, maxb: int, block: int,
                          kv_itemsize: int = 2) -> int:

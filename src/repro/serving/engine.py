@@ -114,7 +114,7 @@ class ServeStats(StatsView):
     series). Field semantics unchanged from the old dataclass."""
 
     PREFIX = "serve"
-    DERIVED = ("tokens_per_second", "mean_occupancy")
+    DERIVED = ("mean_occupancy",)
 
     requests = counter_field()
     tokens_out = counter_field()
@@ -128,11 +128,7 @@ class ServeStats(StatsView):
     switch_s = counter_field(0.0)
     prefill_s = counter_field(0.0)
     exec_s = counter_field(0.0)
-
-    @property
-    def tokens_per_second(self):
-        t = self.switch_s + self.exec_s + self.prefill_s
-        return self.tokens_out / t if t else 0.0
+    table_uploads = counter_field()     # rounds that re-uploaded tables/lengths
 
     @property
     def mean_occupancy(self):
@@ -171,6 +167,7 @@ class _DeviceTableCache:
         self._len_key = None
         self._tables = None
         self._lengths = None
+        self.uploads = 0                # device uploads of either array
 
     def tables(self, rids: Tuple[Optional[int], ...]):
         key = (self.pool.table_version, rids)
@@ -179,6 +176,7 @@ class _DeviceTableCache:
                 self.pool.padded_table(r, self.max_blocks)
                 if r is not None else self._empty for r in rids]))
             self._tab_key = key
+            self.uploads += 1
         return self._tables
 
     def lengths(self, rids: Tuple[Optional[int], ...]):
@@ -188,7 +186,14 @@ class _DeviceTableCache:
                 [self.pool.length(r) if r is not None else 0 for r in rids],
                 np.int32))
             self._len_key = key
+            self.uploads += 1
         return self._lengths
+
+
+@jax.jit
+def sample(logits):
+    """Greedy sampling: each lane's argmax at its last position."""
+    return jnp.argmax(logits[:, -1], axis=-1)
 
 
 # ----------------------------------------------------------------------
@@ -212,16 +217,20 @@ class GreedyDecode:
 
     def round(self, params, active: np.ndarray) -> Dict[int, List[int]]:
         eng = self.engine
-        toks = np.zeros((eng.n_slots, 1), np.int32)
-        for i in np.nonzero(active)[0]:
-            # blocks were fully reserved at admission; only tokens needed
-            toks[i, 0] = eng.slots[i].last_token
-        tables, lengths = eng._device_tables()
+        with trace.span("decode_inputs", cat="engine"):
+            toks = np.zeros((eng.n_slots, 1), np.int32)
+            for i in np.nonzero(active)[0]:
+                # blocks were fully reserved at admission; only tokens needed
+                toks[i, 0] = eng.slots[i].last_token
+            tables, lengths = eng._device_tables()
+            dact = eng._device_active(active)
         logits, pk, pv = eng.runner.extend(params, eng.pool.k, eng.pool.v,
-                                           tables, lengths,
-                                           eng._device_active(active), toks)
+                                           tables, lengths, dact, toks)
         eng.pool.k, eng.pool.v = pk, pv
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
+        with trace.span("sample", cat="engine"):
+            nxt = sample(logits)
+        with trace.span("sync", cat="engine"):
+            nxt = np.asarray(nxt, np.int32)
         return {int(i): [int(nxt[i])] for i in np.nonzero(active)[0]}
 
 
@@ -531,13 +540,16 @@ class ServingEngine:
         self._step_count += 1
         done: List[Request] = []
         with trace.span("step", cat="engine", step=self._step_count) as sp:
-            name = self._pick_expert()
+            with trace.span("pick", cat="engine"):
+                name = self._pick_expert()
             if name is None:
                 return done
             if name != self._active_expert:
                 self._switch_to(name)
-            self._admit(done)
-            self._prefetch_next()
+            with trace.span("admission", cat="engine"):
+                self._admit(done)
+            with trace.span("prefetch_next", cat="engine"):
+                self._prefetch_next()
             active = np.array([s is not None
                                and s.expert == self._active_expert
                                for s in self.slots], bool)
@@ -592,7 +604,7 @@ class ServingEngine:
                 params, self.pool.k, self.pool.v, tables, lengths, active,
                 toks)
             self.pool.k, self.pool.v = pk, pv
-            jnp.argmax(logits[:, -1], axis=-1).block_until_ready()
+            sample(logits).block_until_ready()
             if self.prefix_sharing:
                 # suffix prefill (prefix hits) runs the decode extend at
                 # bucket widths — compile each all-inactive now so a hit
@@ -787,7 +799,9 @@ class ServingEngine:
                         **{"prefill.bucket": S, "prefill.packed": 0}):
             last, k, v = self.runner.prefill_kv(params,
                                                 jnp.asarray(req.tokens[None]))
-            first = int(jnp.argmax(last))
+            first = jnp.argmax(last)
+            with trace.span("sync", cat="engine"):
+                first = int(first)
             self.pool.open(req.rid)
             self.pool.append(req.rid, k, v)
             # commit the request's whole block budget now so admission's
@@ -874,8 +888,9 @@ class ServingEngine:
                         **{"prefill.packed": len(reqs)}) as sp:
             res = self.prefill_runner(params, [r.tokens for r in reqs])
             sp.add(**{"prefill.bucket": res.bucket})
-            firsts = np.asarray(jnp.argmax(res.logits[:len(reqs)], axis=-1),
-                                np.int32)
+            firsts = jnp.argmax(res.logits[:len(reqs)], axis=-1)
+            with trace.span("sync", cat="engine"):
+                firsts = np.asarray(firsts, np.int32)
             # reserve prompt + whole output budget up front (same
             # over-admission guard as the sequential path)
             self.prefill_runner.scatter_into(
@@ -956,7 +971,9 @@ class ServingEngine:
                 params, self.pool.k, self.pool.v, jnp.asarray(tables),
                 jnp.asarray(lengths), jnp.asarray(active), toks)
             self.pool.k, self.pool.v = pk, pv
-            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            nxt = jnp.argmax(logits, axis=-1)
+            with trace.span("sync", cat="engine"):
+                nxt = np.asarray(nxt, np.int32)
         self.stats.prefill_s += time.perf_counter() - t0
         self._registry.counter("serve.prefill_bucket", labels={
             **self._obs_labels, "bucket": g}).inc(len(lanes))
@@ -1063,7 +1080,12 @@ class ServingEngine:
         only when the pool bookkeeping or slot mapping changed (see
         ``_DeviceTableCache``)."""
         rids = self._slot_rids()
-        return self._dev_tables.tables(rids), self._dev_tables.lengths(rids)
+        dev = self._dev_tables
+        before = dev.uploads
+        out = dev.tables(rids), dev.lengths(rids)
+        if dev.uploads != before:
+            self.stats.table_uploads += 1
+        return out
 
     def _device_active(self, active: np.ndarray):
         """Device copy of the active mask, reused while the mask is stable
@@ -1079,22 +1101,23 @@ class ServingEngine:
                         active_slots=int(active.sum())):
             emits = self.policy.round(self._params, active)
         now = time.perf_counter()
-        for i, toks in emits.items():
-            slot = self.slots[i]
-            n = len(toks)
-            if n == 0:
-                continue
-            self.pool.advance(slot.req.rid, n)
-            slot.generated.extend(toks)
-            slot.last_token = toks[-1]
-            slot.req.last_token_s = now
-            self.stats.tokens_out += n
-            if slot.req.on_token is not None:
-                for t in toks:
-                    slot.req.on_token(slot.req, int(t))
-            if slot.remaining <= 0:
-                self._finish(slot, done)
-                self.slots[i] = None         # immediate slot recycling
+        with trace.span("emit", cat="engine"):
+            for i, toks in emits.items():
+                slot = self.slots[i]
+                n = len(toks)
+                if n == 0:
+                    continue
+                self.pool.advance(slot.req.rid, n)
+                slot.generated.extend(toks)
+                slot.last_token = toks[-1]
+                slot.req.last_token_s = now
+                self.stats.tokens_out += n
+                if slot.req.on_token is not None:
+                    for t in toks:
+                        slot.req.on_token(slot.req, int(t))
+                if slot.remaining <= 0:
+                    self._finish(slot, done)
+                    self.slots[i] = None     # immediate slot recycling
         self.stats.exec_s += time.perf_counter() - t0
         self.stats.decode_rounds += 1
         self.stats.occupancy_sum += float(active.sum()) / self.n_slots

@@ -185,7 +185,7 @@ def packed_prefill_fn(cfg: ModelConfig):
             y = y + p["bo"]
         return x + y, (k, v)
 
-    def forward(params, tokens, seg, pos, last_idx):
+    def prefill_packed(params, tokens, seg, pos, last_idx):
         h = T.embed_tokens(cfg, params, tokens)
 
         def body(hh, lp):
@@ -200,7 +200,7 @@ def packed_prefill_fn(cfg: ModelConfig):
         logits = T.unembed(cfg, params, h_last)     # (P, 1, V)
         return logits[:, 0], k[:, 0], v[:, 0]
 
-    return forward
+    return prefill_packed
 
 
 # ----------------------------------------------------------------------

@@ -328,6 +328,64 @@ def test_engine_trace_covers_request_lifecycle(cfg, tmp_path):
         trace.set_tracer(old)
 
 
+def _profile_spans(trace_dir):
+    """``(name, start_ns, end_ns, thread)`` of the program's spans in the
+    ``.xplane.pb`` that a profiler session wrote under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = trace_dir.glob("plugins/profile/*/*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for i, line in enumerate(plane.lines):
+            out.extend((e.name, e.start_ns, e.end_ns, f"{line.name}/{i}")
+                       for e in line.events
+                       if e.name.startswith(("engine.", "kernel.",
+                                             "switch.")))
+    return out
+
+
+def test_engine_spans_land_in_the_profile(cfg, tmp_path):
+    """With the ring tracer off and a profiler session recording, the
+    engine's spans are written into the profile, on its clock, nested as
+    the engine runs them."""
+    old = trace.set_tracer(Tracer())
+    try:
+        eng = _mk_engine(cfg)
+        reqs = _mk_requests(cfg, 4)
+        eng.submit(reqs[0])                # compile outside the session
+        eng.drain()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for r in reqs[1:]:
+                eng.submit(r)
+            assert len(eng.drain()) == 3
+        finally:
+            jax.profiler.stop_trace()
+        assert trace.events() == []        # the ring stayed off
+    finally:
+        trace.set_tracer(old)
+    spans = _profile_spans(tmp_path)
+    names = {s[0] for s in spans}
+    assert {"engine.step", "engine.pick", "engine.admission",
+            "engine.prefetch_next", "engine.prefill", "engine.decode",
+            "engine.decode_inputs", "kernel.decode_kernel", "engine.sample",
+            "engine.sync", "engine.emit"} <= names
+
+    def inside(a, b):
+        return b[3] == a[3] and b[1] <= a[1] and a[2] <= b[2]
+
+    of = lambda n: [s for s in spans if s[0] == n]
+    decodes, steps = of("engine.decode"), of("engine.step")
+    assert decodes and all(any(inside(d, s) for s in steps)
+                           for d in decodes)
+    for child in ("engine.decode_inputs", "kernel.decode_kernel",
+                  "engine.sample"):
+        assert len(of(child)) == len(decodes)
+        assert all(any(inside(c, d) for d in decodes) for c in of(child))
+    # one blocking sync per decode round, inside it
+    assert sum(any(inside(x, d) for d in decodes)
+               for x in of("engine.sync")) == len(decodes)
+
+
 # ----------------------------------------------------------------------
 # failed-prefetch stall attribution (satellite of ISSUE 6)
 # ----------------------------------------------------------------------

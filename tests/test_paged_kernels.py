@@ -242,6 +242,50 @@ def test_fused_extend_matches_xla_extend(cfg, n_kv):
                                       np.asarray(pk[:, row]))
 
 
+# ------------------------------------------------- names on the device
+_SCOPE = {"qkv_rope_paged": "qkv_rope", "decode_paged": "attention",
+          "oproj_ffn_swiglu": "oproj_ffn"}
+
+
+def _op_names(hlo: str):
+    """``(opcode, op_name)`` of every instruction whose op_name is a whole
+    path (the reducers of a ``reduce`` carry only its tail)."""
+    import re
+    return [(op, n) for op, n in re.findall(
+        r"= \S+ ([\w-]+)\(.*op_name=\"([^\"]*)\"", hlo)
+        if n.startswith("jit(decode_step)/")]
+
+
+@pytest.mark.parametrize("backend", ["xla", "fused"])
+def test_decode_step_program_and_scopes(cfg, backend):
+    """The compiled extend step is the program ``decode_step``; the K/V
+    scatter and (fused) every operation of the three kernels carry the
+    named scope of their part of the layer."""
+    from repro.serving.backends import make_backend
+    params = get_model(cfg).init(jax.random.PRNGKey(0))
+    B, block, maxb = 4, 8, 3
+    rows = B * maxb + 1
+    pool = jnp.zeros((cfg.n_layers, rows, block, cfg.n_kv_heads,
+                      cfg.head_dim), jnp.float32)
+    step = make_backend(backend, cfg, rows - 1).extend_fn(B, 1)
+    hlo = jax.jit(step).lower(
+        params, pool, pool, jnp.zeros((B, maxb), jnp.int32),
+        jnp.zeros((B,), jnp.int32), jnp.ones((B,), bool),
+        jnp.zeros((B, 1), jnp.int32)).compile().as_text()
+    assert hlo.startswith("HloModule jit_decode_step")
+    ops = _op_names(hlo)
+    scatters = [n.split("/") for op, n in ops if op == "scatter"]
+    assert scatters and all("kv_scatter" in n for n in scatters)
+    dots = [n.split("/") for op, n in ops if op == "dot"]
+    assert dots and all(any(s in n for s in
+                            ("qkv_rope", "attention", "oproj_ffn", "head"))
+                        for n in dots)
+    if backend == "fused":
+        for kernel, scope in _SCOPE.items():
+            mine = [n.split("/") for _, n in ops if kernel in n.split("/")]
+            assert mine and all(scope in n for n in mine), kernel
+
+
 # ----------------------------------------------------------- backend seam
 def test_backend_seam_selection_and_validation(cfg):
     runner = make_runner(cfg, scratch_row=7, backend="fused")
@@ -339,13 +383,17 @@ def test_device_table_cache_reuses_arrays(cfg):
                        max_new_tokens=4))
     eng.step()
     t1, l1 = eng._device_tables()
+    uploads = eng.stats.table_uploads
     t2, l2 = eng._device_tables()
     assert t1 is t2 and l1 is l2
+    assert eng.stats.table_uploads == uploads    # a cached round: no upload
     v_tab, v_len = eng.pool.table_version, eng.pool.length_version
     eng.step()                       # advances lengths (maybe allocs blocks)
     assert eng.pool.length_version > v_len
+    uploads = eng.stats.table_uploads
     t3, l3 = eng._device_tables()
     assert l3 is not l1
+    assert eng.stats.table_uploads == uploads + 1   # one per round, not array
     if eng.pool.table_version == v_tab:      # no new block this round
         assert t3 is t1                      # table upload skipped entirely
     act = np.array([True, False])

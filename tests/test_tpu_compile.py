@@ -112,3 +112,33 @@ def test_fused_decode_step_compiles(cfg, shape):
         params, pool, pool, shape((B, maxb), jnp.int32),
         shape((B,), jnp.int32), shape((B,), jnp.bool_),
         shape((B, 1), jnp.int32)).compile())
+
+
+def test_fused_decode_step_names_program_and_kernels(cfg, shape):
+    """On the chip's compiler the extend step is the program
+    ``decode_step`` and each Pallas kernel is a custom call named after
+    the kernel, under the named scope of its part of the layer."""
+    import re
+    from repro.models import get_model
+    from repro.models.common import abstract_params
+    from repro.serving.backends import FusedPagedBackend
+    c = dataclasses.replace(cfg, n_layers=2)
+    rows, maxb = B * MAX_LEN // BLOCK + 1, MAX_LEN // BLOCK
+    params = jax.tree.map(lambda s: shape(s.shape, s.dtype),
+                          abstract_params(get_model(c).param_specs()))
+    pool = shape((c.n_layers, rows, BLOCK, c.n_kv_heads, c.head_dim))
+    step = FusedPagedBackend(c, rows - 1, interpret=False).extend_fn(B, 1)
+    hlo = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pool, pool, shape((B, maxb), jnp.int32),
+        shape((B,), jnp.int32), shape((B,), jnp.bool_),
+        shape((B, 1), jnp.int32)).compile().as_text()
+    assert hlo.startswith("HloModule jit_decode_step")
+    calls = re.findall(r"%(\w+)\.\d+ = .*custom_call_target=\"tpu_custom_call"
+                       r"\".*op_name=\"([^\"]*)\"", hlo)
+    scope = {"qkv_rope_paged": "qkv_rope", "decode_paged": "attention",
+             "oproj_ffn_swiglu": "oproj_ffn"}
+    assert sorted({k for k, _ in calls}) == sorted(scope)
+    for kernel, op_name in calls:
+        parts = op_name.split("/")
+        assert scope[kernel] in parts and kernel in parts, op_name
+
