@@ -26,7 +26,12 @@ these take the engine's layouts directly, no weight concat / cache copy):
   * ``oproj_ffn_swiglu``: the whole layer epilogue — attention out-proj +
     residual + RMSNorm + SwiGLU + residual — with the post-attention
     activation pinned in VMEM between the two residual adds; Wo and the
-    FFN stream in tiles sized to the scoped VMEM (``lane_tile``).
+    FFN stream in tiles sized to the scoped VMEM (``lane_tile``), read out
+    of the stacked (L, ...) weights by index maps offset by a
+    scalar-prefetched layer index, so the layer scan slices none of them.
+    (Wq/Wk/Wv cannot be streamed so: their per-head ``(D, dh)`` tiles need
+    the ``(D, H*dh)`` view, which on the chip's tiled layout is a relayout
+    of ``(D, H, dh)``, not a free view of the stack.)
   * ``ffn_swiglu(residual=False)``: the tensor-parallel partial form (down-
     proj partials psum'd across shards before the residual).
 
@@ -278,8 +283,8 @@ def ffn_swiglu(x, norm_scale, w_gate, w_up, w_down, *, block_f=None,
 # out-proj + residual + norm + SwiGLU FFN + residual (the layer epilogue)
 # ----------------------------------------------------------------------
 
-def _oproj_ffn_kernel(x_ref, attn_ref, wo_ref, scale_ref, wg_ref, wu_ref,
-                      wd_ref, o_ref, y_ref, acc_ref, *, n_o, n_f):
+def _oproj_ffn_kernel(layer_ref, x_ref, attn_ref, wo_ref, scale_ref, wg_ref,
+                      wu_ref, wd_ref, o_ref, y_ref, acc_ref, *, n_o, n_f):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -315,24 +320,29 @@ def _oproj_ffn_kernel(x_ref, attn_ref, wo_ref, scale_ref, wg_ref, wu_ref,
         o_ref[...] = (y_ref[...] + acc_ref[...]).astype(o_ref.dtype)
 
 
-def oproj_ffn_swiglu(x, attn_out, w_o, norm_scale, w_gate, w_up, w_down, *,
-                     block_f=None, interpret=False):
-    """The whole decoder-layer epilogue in one kernel:
+def oproj_ffn_swiglu(x, attn_out, w_o, norm_scale, w_gate, w_up, w_down,
+                     layer, *, block_f=None, interpret=False):
+    """The whole epilogue of decoder layer ``layer`` in one kernel:
 
-        y = x + attn_out @ w_o                 (attention out-proj + residual)
+        y = x + attn_out @ w_o[layer]          (attention out-proj + residual)
         return y + SwiGLU(RMSNorm(y))          (FFN + residual)
 
-    x (B,D); attn_out (B, Hq*dh); w_o (Hq*dh, D) — the engine's native
-    (Hq,dh,D) ``wo`` reshaped (contiguous, no copy). One sequential grid
-    streams Wo in row tiles, then the FFN in ``block_f``-wide hidden tiles;
-    every weight byte is read once and ``y`` never round-trips to HBM
-    between out-proj and FFN. Each index map clamps outside its own phase,
-    so the first FFN tiles prefetch during the out-proj. Wo's tile, and
-    ``block_f`` by default, are the widest inside ``WEIGHT_TILE_BYTES``.
+    x (B,D); attn_out (B, Hq*dh); the engine's stacked weights w_o
+    (L, Hq*dh, D) (its native (L,Hq,dh,D) ``wo`` reshaped, no copy),
+    w_gate/w_up (L, D, F), w_down (L, F, D); ``layer`` an int32 scalar on
+    the scalar-prefetch channel. Each weight streams from its free 2-D view
+    ``(L*rows, cols)``, its index map offset by the layer's row blocks, so
+    no layer is sliced out. A single layer's weights are ``w[None]`` with
+    layer 0. One sequential grid streams Wo in row tiles, then the FFN in
+    ``block_f``-wide hidden tiles; every weight byte is read once and ``y``
+    never round-trips to HBM between out-proj and FFN. Each index map
+    clamps outside its own phase, so the first FFN tiles prefetch during
+    the out-proj. Wo's tile, and ``block_f`` by default, are the widest
+    inside ``WEIGHT_TILE_BYTES``.
     """
     B, D = x.shape
     HD = attn_out.shape[1]
-    F = w_gate.shape[1]
+    L, _, F = w_gate.shape
     bo = lane_tile(HD, D * w_o.dtype.itemsize)
     bf = min(block_f or lane_tile(F, D * w_gate.dtype.itemsize), F)
     assert HD % bo == 0 and F % bf == 0, (HD, bo, F, bf)
@@ -345,24 +355,31 @@ def oproj_ffn_swiglu(x, attn_out, w_o, norm_scale, w_gate, w_up, w_down, *,
     def f_idx(i):
         return jnp.maximum(i - n_o, 0)
 
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(n_o + n_f,),
         in_specs=[
-            pl.BlockSpec((B, D), lambda i: (0, 0)),
-            pl.BlockSpec((B, bo), lambda i: (0, o_idx(i))),
-            pl.BlockSpec((bo, D), lambda i: (o_idx(i), 0)),
-            pl.BlockSpec((1, D), lambda i: (0, 0)),
-            pl.BlockSpec((D, bf), lambda i: (0, f_idx(i))),
-            pl.BlockSpec((D, bf), lambda i: (0, f_idx(i))),
-            pl.BlockSpec((bf, D), lambda i: (f_idx(i), 0)),
+            pl.BlockSpec((B, D), lambda i, l: (0, 0)),
+            pl.BlockSpec((B, bo), lambda i, l: (0, o_idx(i))),
+            pl.BlockSpec((bo, D), lambda i, l: (l[0] * n_o + o_idx(i), 0)),
+            pl.BlockSpec((1, D), lambda i, l: (0, 0)),
+            pl.BlockSpec((D, bf), lambda i, l: (l[0], f_idx(i))),
+            pl.BlockSpec((D, bf), lambda i, l: (l[0], f_idx(i))),
+            pl.BlockSpec((bf, D), lambda i, l: (l[0] * n_f + f_idx(i), 0)),
         ],
-        out_specs=pl.BlockSpec((B, D), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, D), x.dtype),
+        out_specs=pl.BlockSpec((B, D), lambda i, l: (0, 0)),
         scratch_shapes=[
             pltpu.VMEM((B, D), jnp.float32),
             pltpu.VMEM((B, D), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, D), x.dtype),
         interpret=interpret,
         name="oproj_ffn_swiglu",
-    )(x, attn_out, w_o, norm_scale.reshape(1, D), w_gate, w_up, w_down)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), x, attn_out,
+      w_o.reshape(L * HD, D), norm_scale.reshape(1, D),
+      w_gate.reshape(L * D, F), w_up.reshape(L * D, F),
+      w_down.reshape(L * F, D))

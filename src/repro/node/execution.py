@@ -171,7 +171,7 @@ def _tp_fused_paged_extend(cfg: ModelConfig, tp: int, vocab_sharded: bool,
 
     B, g = tokens.shape
     assert g == 1
-    block = pk.shape[2]
+    n_layers, n_rows, block = pk.shape[:3]
     maxb = tables.shape[1]
     it = resolve_interpret(interpret)
     didx = jax.lax.axis_index("model")
@@ -197,15 +197,20 @@ def _tp_fused_paged_extend(cfg: ModelConfig, tp: int, vocab_sharded: bool,
     off = pos % block
     len1 = lengths + 1
 
-    def body(hh, xs):
-        lp, kp, vp = xs                    # kp (rows, block, Hkv_l, dh)
+    # the pools ride the carry and take each layer's K/V in place, as in
+    # serving.backends.fused_paged_extend
+    def body(carry, xs):
+        hh, pk, pv = carry                 # pk (L, rows, block, Hkv_l, dh)
+        lp, l = xs
         p = lp["attn"]
         q, k, v = qkv_rope_paged(hh, p["norm"]["scale"], p["wq"], p["wk"],
                                  p["wv"], pos, theta=cfg.rope_theta,
                                  interpret=it)
-        kp = kp.at[rows, off].set(k.astype(kp.dtype))
-        vp = vp.at[rows, off].set(v.astype(vp.dtype))
-        o = decode_paged(q, kp, vp, tables, len1, interpret=it)   # (B,Hq_l,dh)
+        pk = pk.at[l, rows, off].set(k.astype(pk.dtype))
+        pv = pv.at[l, rows, off].set(v.astype(pv.dtype))
+        o = decode_paged(q, pk.reshape(-1, *pk.shape[2:]),
+                         pv.reshape(-1, *pv.shape[2:]), tables + l * n_rows,
+                         len1, interpret=it)                      # (B,Hq_l,dh)
         y = jnp.einsum("bhk,hkd->bd", o.astype(hh.dtype), p["wo"])  # partial
         hh = hh + jax.lax.psum(y, "model")                        # reduce #1
         mp = lp["mlp"]
@@ -213,9 +218,11 @@ def _tp_fused_paged_extend(cfg: ModelConfig, tp: int, vocab_sharded: bool,
                        mp["wi_up"], mp["wo"], residual=False,
                        interpret=it)                              # partial
         hh = hh + jax.lax.psum(y, "model")                        # reduce #2
-        return hh, (kp, vp)
+        return (hh, pk, pv), None
 
-    h, (pk, pv) = jax.lax.scan(body, h, (params["layers"], pk, pv))
+    (h, pk, pv), _ = jax.lax.scan(
+        body, (h, pk, pv),
+        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
     h = L.apply_norm(cfg, params["final_norm"], h)[:, None]       # (B,1,D)
     if cfg.tie_embeddings:
         logits = jnp.einsum("bsd,vd->bsv", h, tok_tab)

@@ -139,7 +139,16 @@ def fused_paged_extend(cfg: ModelConfig, params, pk, pv, tables, lengths,
     identical to ``xla_paged_extend`` with g=1 — including the masking
     convention: a lane attends positions ``kpos <= lengths``, i.e. ``len1 =
     lengths + 1`` valid cache slots after this step's scatter; inactive and
-    empty lanes compute finite garbage the caller ignores."""
+    empty lanes compute finite garbage the caller ignores.
+
+    The layer scan slices no pool and stacks none back: it carries the
+    donated pools and scatters layer ``l``'s new K/V into them in place,
+    and ``decode_paged`` reads them as one free ``(L*rows, ...)`` view
+    through tables offset by ``l * rows``. The epilogue streams layer ``l``
+    of the stacked Wo and FFN weights by index map. Only Wq/Wk/Wv and the
+    norm scales ride the scan's ``xs``: the prologue's per-head tiles need
+    each layer's Wq/Wk/Wv relaid out as ``(D, H*dh)`` (not a free view on
+    the chip's tiled layout), which XLA does per layer in on-chip memory."""
     from repro.kernels.fused_decode.kernel import (qkv_rope_paged,
                                                    oproj_ffn_swiglu)
     from repro.kernels.flash_attention.ops import decode_paged
@@ -149,7 +158,7 @@ def fused_paged_extend(cfg: ModelConfig, params, pk, pv, tables, lengths,
 
     B, g = tokens.shape
     assert g == 1, "fused_paged_extend is the single-token hot path"
-    block = pk.shape[2]
+    n_layers, n_rows, block = pk.shape[:3]
     maxb = tables.shape[1]
     Hq, dh, D = cfg.n_heads, cfg.head_dim, cfg.d_model
     it = resolve_interpret(interpret)
@@ -162,29 +171,34 @@ def fused_paged_extend(cfg: ModelConfig, params, pk, pv, tables, lengths,
     rows = jnp.where(active, rows, jnp.int32(scratch_row))
     off = pos % block
     len1 = lengths + 1
+    lw = params["layers"]
+    a, mlp = lw["attn"], lw["mlp"]
+    wo = a["wo"].reshape(n_layers, Hq * dh, D)
+    per_layer = (a["norm"]["scale"], a["wq"], a["wk"], a["wv"],
+                 lw["mlp_norm"]["scale"])
 
-    def body(hh, xs):
-        lp, kp, vp = xs                    # kp (rows, block, Hkv, dh)
-        p = lp["attn"]
+    def body(carry, xs):
+        hh, pk, pv = carry                 # pk (L, rows, block, Hkv, dh)
+        (s_attn, wq, wk, wv, s_mlp), l = xs
         with jax.named_scope("qkv_rope"):
-            q, k, v = qkv_rope_paged(hh, p["norm"]["scale"], p["wq"],
-                                     p["wk"], p["wv"], pos,
+            q, k, v = qkv_rope_paged(hh, s_attn, wq, wk, wv, pos,
                                      theta=cfg.rope_theta, interpret=it)
         with jax.named_scope("kv_scatter"):
-            kp = kp.at[rows, off].set(k.astype(kp.dtype))
-            vp = vp.at[rows, off].set(v.astype(vp.dtype))
+            pk = pk.at[l, rows, off].set(k.astype(pk.dtype))
+            pv = pv.at[l, rows, off].set(v.astype(pv.dtype))
         with jax.named_scope("attention"):
-            o = decode_paged(q, kp, vp, tables, len1,
+            o = decode_paged(q, pk.reshape(-1, *pk.shape[2:]),
+                             pv.reshape(-1, *pv.shape[2:]),
+                             tables + l * n_rows, len1,
                              interpret=it)                        # (B,Hq,dh)
         with jax.named_scope("oproj_ffn"):
-            hh = oproj_ffn_swiglu(hh, o.reshape(B, Hq * dh),
-                                  p["wo"].reshape(Hq * dh, D),
-                                  lp["mlp_norm"]["scale"],
-                                  lp["mlp"]["wi_gate"], lp["mlp"]["wi_up"],
-                                  lp["mlp"]["wo"], interpret=it)
-        return hh, (kp, vp)
+            hh = oproj_ffn_swiglu(hh, o.reshape(B, Hq * dh), wo, s_mlp,
+                                  mlp["wi_gate"], mlp["wi_up"], mlp["wo"],
+                                  l, interpret=it)
+        return (hh, pk, pv), None
 
-    h, (pk, pv) = jax.lax.scan(body, h, (params["layers"], pk, pv))
+    (h, pk, pv), _ = jax.lax.scan(
+        body, (h, pk, pv), (per_layer, jnp.arange(n_layers, dtype=jnp.int32)))
     with jax.named_scope("head"):
         h = L.apply_norm(cfg, params["final_norm"], h)[:, None]   # (B,1,D)
         logits = T.unembed(cfg, params, h)

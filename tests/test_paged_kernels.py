@@ -173,8 +173,8 @@ def test_oproj_ffn_swiglu_matches_reference(cfg, wide):
     wu = jnp.asarray(rs.standard_normal((D, F)) * 0.05, jnp.float32)
     wd = jnp.asarray(rs.standard_normal((F, D)) * 0.05, jnp.float32)
 
-    got = oproj_ffn_swiglu(x, attn, wo, scale, wg, wu, wd, block_f=64,
-                           interpret=True)
+    got = oproj_ffn_swiglu(x, attn, wo[None], scale, wg[None], wu[None],
+                           wd[None], 0, block_f=64, interpret=True)
     y = x + attn @ wo
     yn = L.rms_norm(y, scale)
     g, u = yn @ wg, yn @ wu
@@ -240,6 +240,51 @@ def test_fused_extend_matches_xla_extend(cfg, n_kv):
     for row in np.asarray(tables)[lane]:
         np.testing.assert_array_equal(np.asarray(pk_f[:, row]),
                                       np.asarray(pk[:, row]))
+
+
+def test_fused_extend_writes_each_layer_slab_in_place(cfg):
+    """The fused step carries the stacked pool through the layer scan and
+    scatters into it in place: after one step, layer l's slab holds the new
+    K/V at (row, off) of every active lane and at (scratch, off) of the
+    inactive one, equal to the XLA step's, and every other entry of every
+    layer is bit-identical to before. Logits match the XLA step."""
+    c = _gqa_cfg(cfg, 2)
+    assert c.n_layers >= 3
+    params = _f32(get_model(c).init(jax.random.PRNGKey(7)))
+    B, block, maxb = 4, 8, 3
+    rows = B * maxb + 1
+    scratch = rows - 1
+    rs = np.random.RandomState(8)
+    shape = (c.n_layers, rows, block, c.n_kv_heads, c.head_dim)
+    pk = jnp.asarray(rs.standard_normal(shape) * 0.1, jnp.float32)
+    pv = jnp.asarray(rs.standard_normal(shape) * 0.1, jnp.float32)
+    tables = jnp.asarray(
+        rs.permutation(rows - 1)[:B * maxb].reshape(B, maxb), jnp.int32)
+    lengths = np.asarray([3, 8, 13, 23], np.int32)
+    active = np.asarray([True, False, True, True])
+    tokens = jnp.asarray(rs.randint(0, c.vocab_size, (B, 1)), jnp.int32)
+    before_k, before_v = np.asarray(pk), np.asarray(pv)
+
+    lg_x, pk_x, pv_x = xla_paged_extend(c, params, pk, pv, tables, lengths,
+                                        active, tokens, scratch)
+    lg_f, pk_f, pv_f = fused_paged_extend(c, params, pk, pv, tables, lengths,
+                                          active, tokens, scratch,
+                                          interpret=True)
+    np.testing.assert_allclose(np.asarray(lg_f), np.asarray(lg_x),
+                               rtol=2e-4, atol=2e-4)
+    tb = np.asarray(tables)
+    row = np.where(active, tb[np.arange(B), lengths // block], scratch)
+    off = lengths % block
+    written = np.zeros(shape[:3], bool)
+    written[:, row, off] = True
+    for got, ref, before in ((pk_f, pk_x, before_k), (pv_f, pv_x, before_v)):
+        got, ref = np.asarray(got), np.asarray(ref)
+        np.testing.assert_array_equal(got[~written], before[~written])
+        for l in range(c.n_layers):
+            new = got[l, row, off]                    # (B, Hkv, dh)
+            np.testing.assert_allclose(new, ref[l, row, off],
+                                       rtol=2e-4, atol=2e-4)
+            assert not np.allclose(new, before[l, row, off])
 
 
 # ------------------------------------------------- names on the device

@@ -13,6 +13,8 @@ so every pytest worker collects the same tests and only the worker that
 runs this file loads the TPU library.
 """
 import dataclasses
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -90,10 +92,10 @@ def test_oproj_ffn_swiglu_compiles(cfg, shape):
     from repro.kernels.fused_decode.kernel import oproj_ffn_swiglu
     D, HD, F = cfg.d_model, cfg.n_heads * cfg.head_dim, cfg.d_ff
     f = lambda x, a, wo, s, wg, wu, wd: oproj_ffn_swiglu(
-        x, a, wo, s, wg, wu, wd, interpret=False)
+        x, a, wo, s, wg, wu, wd, 0, interpret=False)
     _check(jax.jit(f).lower(
-        shape((B, D)), shape((B, HD)), shape((HD, D)), shape((D,)),
-        shape((D, F)), shape((D, F)), shape((F, D))).compile())
+        shape((B, D)), shape((B, HD)), shape((1, HD, D)), shape((D,)),
+        shape((1, D, F)), shape((1, D, F)), shape((1, F, D))).compile())
 
 
 def test_fused_decode_step_compiles(cfg, shape):
@@ -142,3 +144,74 @@ def test_fused_decode_step_names_program_and_kernels(cfg, shape):
         parts = op_name.split("/")
         assert scope[kernel] in parts and kernel in parts, op_name
 
+
+
+_INSTR = re.compile(r"%([\w.-]+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _instructions(hlo: str):
+    """``(name, dtype, dims, opcode)`` of every instruction of every
+    computation in a compiled program's text."""
+    return [(n, t, tuple(int(d) for d in dims.split(",") if d), op)
+            for n, t, dims, op in _INSTR.findall(hlo)]
+
+
+def _does(name: str, op: str, kinds) -> bool:
+    """Whether an instruction is, or is a fusion rooted in, one of
+    ``kinds`` (XLA names a fusion after the operations it fuses)."""
+    return op in kinds or (op == "fusion" and any(k in name for k in kinds))
+
+
+def test_fused_decode_step_updates_pool_and_reads_weights_in_place(shape):
+    """At deepseek-llm-7b's widths (32 kv heads of 128, block 16, depth 4)
+    with the pool donated, the compiled step neither slices a layer's slab
+    out of the pool nor stacks or copies one back: the only pool-sized
+    instructions are the two in-place K/V scatters. No Wo or FFN weight is
+    sliced out per layer (the epilogue reads the stack by index map). The
+    only per-layer weight slices left are Wq/Wk/Wv's, which the prologue
+    needs relaid out as ``(D, H*dh)``: not a free view of ``(D, H, dh)`` on
+    the chip's tiled layout. The step's temporaries stay under one layer's
+    down-projection."""
+    from repro.models import get_model
+    from repro.models.common import abstract_params
+    from repro.serving.backends import fused_paged_extend
+    c = dataclasses.replace(get_config("granite-8b"), n_layers=DEPTH,
+                            n_kv_heads=32, d_ff=11008, vocab_size=102400,
+                            rope_theta=1e4)
+    D, F, Hq, Hkv, dh = c.d_model, c.d_ff, c.n_heads, c.n_kv_heads, c.head_dim
+    b = 16
+    rows, maxb = b * MAX_LEN // BLOCK + 1, MAX_LEN // BLOCK
+    params = jax.tree.map(lambda s: shape(s.shape, s.dtype),
+                          abstract_params(get_model(c).param_specs()))
+    pool = shape((DEPTH, rows, BLOCK, Hkv, dh))
+    f = lambda p, pk, pv, t, n, a, tk: fused_paged_extend(
+        c, p, pk, pv, t, n, a, tk, rows - 1, interpret=False)
+    compiled = jax.jit(f, donate_argnums=(1, 2)).lower(
+        params, pool, pool, shape((b, maxb), jnp.int32),
+        shape((b,), jnp.int32), shape((b,), jnp.bool_),
+        shape((b, 1), jnp.int32)).compile()
+    ins = _instructions(compiled.as_text())
+
+    slab = (rows, BLOCK, Hkv, dh)
+    moved = [(n, op) for n, _, dims, op in ins
+             if dims in (pool.shape, slab, (1,) + slab)
+             and _does(n, op, ("copy", "dynamic-slice",
+                               "dynamic-update-slice"))]
+    assert not moved, moved
+    scatters = [n for n, _, dims, op in ins
+                if dims == pool.shape and op in ("fusion", "scatter")
+                and not _does(n, op, ("copy", "dynamic-slice",
+                                      "dynamic-update-slice"))]
+    assert scatters, "the K/V scatters write the carried pool"
+
+    epilogue = {(D, F), (F, D), (Hq * dh, D), (Hq, dh, D)}
+    qkv = {(D, Hq, dh), (D, Hkv, dh)}
+    sliced = [(n, dims) for n, _, dims, op in ins
+              if _does(n, op, ("dynamic-slice",))
+              and math.prod(dims) >= D * Hkv * dh]
+    squeeze = lambda dims: tuple(d for d in dims if d != 1)
+    assert not [s for s in sliced if squeeze(s[1]) in epilogue], sliced
+    assert {squeeze(d) for _, d in sliced} <= qkv, sliced
+
+    w_down = D * F * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < w_down
