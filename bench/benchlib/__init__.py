@@ -1,8 +1,10 @@
 """The benchmark's own library: traffic generation, weights made from the
 seed, the drive loop, trace reduction, metric arithmetic and the output
-check. It imports the program under test (``src/repro``) only in
-``deploy.py`` and ``drive.py``; the yardstick (reference, rooflines, peaks,
-percentiles, trace reduction) never does."""
+check. What depends on a model's architecture is in ``bench/arch/``. The
+program under test (``src/repro``) is imported only in ``deploy.py``,
+``drive.py`` and the architecture modules' ``model_config``; the yardstick
+(reference, rooflines, peaks, percentiles, trace reduction) never imports
+it."""
 from __future__ import annotations
 
 from pathlib import Path

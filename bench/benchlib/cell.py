@@ -62,7 +62,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     ``fault(dep)`` plants a fault in the deployment before the window."""
     m = spec.config(cell["config"], bench)
     tp = spec.traffic(cell["traffic"], bench)
-    dep = Deployment(m, seed)
+    dep = Deployment(m, seed, bench)
     t = traffic.generate(tp, seed, dep.expert_names(), m["vocab_size"],
                          seconds)
     log(f"setup: {len(dep.hosts)} experts x {dep.expert_bytes} bytes built "
@@ -169,7 +169,7 @@ def report(rec, ctx) -> None:
         log(f"trace: window {t['window_s']:.6f}s busy {t['busy_s']:.6f}s "
             f"gaps {t['n_gaps']}; {len(rec.rounds)} decode rounds and "
             f"{len(rec.prefills)} prefill calls traced")
-        for k in rooflines.kernels(ctx["bench"]):
+        for k in rooflines.kernels(ctx["m"], ctx["bench"]):
             labs = [x for x, n in ctx["kernels"].items() if n == k]
             log(f"kernel {k}: {sum(t['ops'][x] for x in labs)!r}s in "
                 f"{sum(t['calls'][x] for x in labs)!r} calls, "

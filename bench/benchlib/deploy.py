@@ -3,8 +3,10 @@
 Everything here goes through the program's normal serving path: the
 composition of experts over the host store and the HBM weight tier, and
 ``ServingEngine`` with the configuration's backend, prefill mode and
-buckets. Weights come from ``benchlib.weights``; the benchmark keeps its own
-host trees for the reference and marks them read-only.
+buckets. The program's ``ModelConfig`` comes from the configuration's
+architecture module (``spec.arch``), weights from ``benchlib.weights``; the
+benchmark keeps its own host trees for the reference and marks them
+read-only.
 
 Keys of a configuration's ``deployment``: ``experts`` (registered in the
 capacity tier), ``hbm_tier_experts`` (the weight tier's size in experts),
@@ -17,28 +19,14 @@ absent).
 from __future__ import annotations
 
 import time
+from pathlib import Path
 from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchlib import weights
-
-
-def model_config(m: dict):
-    """The program's ``ModelConfig`` for a configuration file's model keys
-    (Llama architecture: RMSNorm, SwiGLU, full RoPE, no biases, untied)."""
-    from repro.configs.base import ModelConfig
-    if m.get("hidden_act", "silu") != "silu" or m.get("attention_bias") \
-            or m.get("mlp_bias") or m.get("tie_word_embeddings"):
-        raise ValueError(f"{m['name']}: not a plain Llama-architecture model")
-    return ModelConfig(
-        name=m["name"], family="dense", n_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"], n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"], head_dim=m["head_dim"],
-        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"], norm="rms",
-        act="swiglu", rope_style="full", rope_theta=float(m["rope_theta"]))
+from benchlib import BENCH_DIR, spec, weights
 
 
 def make_store(spec):
@@ -60,16 +48,16 @@ class Deployment:
     cell's own shapes, and the HBM tier filled with the most popular
     experts."""
 
-    def __init__(self, m: dict, seed: int):
+    def __init__(self, m: dict, seed: int, bench: Path = BENCH_DIR):
         from repro.core import CompositionOfExperts, ExpertHandle, HashRouter
         from repro.serving import ServingEngine
 
         dep = m["deployment"]
         self.m, self.dep = m, dep
-        self.cfg = model_config(m)
+        self.cfg = spec.arch(m, bench).model_config(m)
         t = time.perf_counter()
         hosts, self.expert_bytes = weights.build_experts(m, dep["experts"],
-                                                         seed)
+                                                         seed, bench)
         self.build_s = time.perf_counter() - t
         self.hosts: Dict[str, dict] = {}
         coe = CompositionOfExperts(
@@ -106,8 +94,15 @@ class Deployment:
             for n in range(1, max_pack + 1):
                 jnp.argmax(res.logits[:n], axis=-1).block_until_ready()
         self.fill_tier(popularity)
-        jax.block_until_ready((eng.pool.k, eng.pool.v))
+        self.sync()
         return time.perf_counter() - t
+
+    def sync(self) -> None:
+        """Wait until the device has written every array the engine's K/V
+        pool holds, whatever the pool names them."""
+        pool = self.engine.pool
+        jax.block_until_ready([x for x in vars(pool).values()
+                               if isinstance(x, jax.Array)])
 
     def fill_tier(self, popularity: List[str]) -> None:
         """Load the most popular experts into the HBM tier, the most
@@ -118,13 +113,14 @@ class Deployment:
 
     def close(self) -> None:
         """Free the program's device state (weight tier, pool, compiled
-        steps), so the reference can run in the room it held."""
+        steps), so the reference can run in the room it held. The engine
+        drops its pool object, so every array the pool holds goes, whatever
+        its name; no local reference to the engine outlives the collection,
+        which frees the engine's reference cycles."""
         self.coe.cache.close()
         for name in list(self.coe.cache.expert_ids()):
             self.coe.cache.drop(name)
-        eng = self.engine
-        eng.pool.k = eng.pool.v = None
-        eng._params = None
+        self.engine.pool = self.engine._params = None
         self.engine = self.coe = None
         import gc
         gc.collect()

@@ -206,7 +206,7 @@ def run_window(dep, traffic: Traffic, seconds: float, trace_dir=None,
     def stop_trace():
         """End the trace at a step boundary once the device is done;
         returns the seconds writing it out paused the loop."""
-        jax.block_until_ready((eng.pool.k, eng.pool.v))
+        dep.sync()
         t_stop = time.perf_counter()
         traced.__exit__(None, None, None)
         tracing["on"] = False

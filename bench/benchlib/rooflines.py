@@ -1,16 +1,18 @@
 """Shared arithmetic of the ``*_roofline`` readers: a kernel's share of its
 roofline over the traced window.
 
-Each ``bench/roofline/<kernel>.py`` with a ``match`` function names one
-Pallas kernel: ``match(op, m)`` tells from an operation's parsed label
-(``parse``) and the configuration whether the operation runs that kernel,
-by the shapes the kernel takes, so the reading does not hang on the names
-XLA gives instructions. ``cost(m, live, n_slots)`` gives the FLOPs and
-bytes that one call, one layer of one decode round, needs.
+Each ``bench/roofline/<kernel>.py`` names one Pallas kernel: ``match(op,
+m)`` tells from an operation's parsed label (``parse``) and the
+configuration whether the operation runs that kernel, by the shapes the
+kernel takes, so the reading does not hang on the names XLA gives
+instructions. ``cost(m, live, n_slots)`` gives the FLOPs and bytes that one
+call of the kernel in a decode round needs. A configuration's architecture
+(``spec.arch``) lists the kernels it runs (``KERNELS``) and how many calls
+of each one decode round makes (``calls_per_round``).
 
 For each traced decode round, the least time the chip could take for the
-round's calls (one per layer) is the larger of FLOPs over peak FLOP/s and
-bytes over peak bytes/s; their sum over the kernel's device time in the
+round's calls is their number times the larger of FLOPs over peak FLOP/s
+and bytes over peak bytes/s; their sum over the kernel's device time in the
 trace is the share, in %."""
 from __future__ import annotations
 
@@ -52,20 +54,16 @@ def shape(t: str) -> tuple:
     return dt, tuple(int(d) for d in dims.split(",") if d)
 
 
-def kernels(bench: Path = BENCH_DIR) -> Dict[str, object]:
-    """Every roofline module that names a kernel, by file stem."""
-    out = {}
-    for p in sorted((bench / "roofline").glob("*.py")):
-        mod = spec.roofline(p.stem, bench)
-        if hasattr(mod, "match"):
-            out[p.stem] = mod
-    return out
+def kernels(m: dict, bench: Path = BENCH_DIR) -> Dict[str, object]:
+    """The roofline module of each kernel ``m``'s architecture lists, by
+    name."""
+    return {k: spec.roofline(k, bench) for k in spec.arch(m, bench).KERNELS}
 
 
 def names(red: dict, m: dict, bench: Path = BENCH_DIR) -> Dict[str, str]:
-    """Label -> kernel, for every traced operation a roofline module
-    names."""
-    mods = kernels(bench)
+    """Label -> kernel, for every traced operation that a roofline module
+    of ``m``'s architecture names."""
+    mods = kernels(m, bench)
     out = {}
     for lab in red["ops"]:
         op = parse(lab)
@@ -81,15 +79,17 @@ def share(rec, ctx, kernel: str) -> Optional[float]:
     red, peaks, m = ctx["trace"], ctx["peaks"], ctx["m"]
     if red is None or peaks is None or not rec.rounds:
         return None
-    mod = spec.roofline(kernel, ctx.get("bench", BENCH_DIR))
+    bench = ctx.get("bench", BENCH_DIR)
+    mod, arch = spec.roofline(kernel, bench), spec.arch(m, bench)
     seconds = sum(v for k, v in red["ops"].items()
                   if ctx["kernels"].get(k) == kernel)
     if seconds <= 0:
         return None
+    calls = arch.calls_per_round(m, kernel)
     least = 0.0
     for r in rec.rounds:
         flops, nbytes = mod.cost(m, r["live"], r["n_slots"])
-        least += m["num_hidden_layers"] * max(
+        least += calls * max(
             flops / peaks["bf16_flops_per_s"],
             nbytes / peaks["hbm_bytes_per_s"])
     return 100.0 * least / seconds

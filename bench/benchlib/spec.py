@@ -2,13 +2,19 @@
 
 * configuration ``<c>``  -> ``bench/configs/<c>.json`` (its ``reference``
   key names ``bench/references/<r>.py``)
+* architecture           -> ``bench/arch/<model_type>.py``, by the
+  configuration's ``model_type`` (``model_config``, ``check_config``,
+  ``shapes``, ``EXPERT_LEAF``, ``decode_flops``, ``prefill_flops``,
+  ``KERNELS``, ``calls_per_round``)
 * traffic mix ``<t>``    -> ``bench/traffic/<t>.json``
 * per-layer metric ``<m>`` -> ``bench/metrics/<m>.py`` (``read(rec)``)
-* kernel roofline ``<k>`` -> ``bench/roofline/<k>.py`` (``cost(m, call)``)
+* kernel roofline ``<k>`` -> ``bench/roofline/<k>.py`` (``match(op, m)``,
+  ``cost(m, live, n_slots)``), for each ``<k>`` of the architecture's
+  ``KERNELS``
 * peaks                  -> ``bench/peaks.json``, keyed by ``device_kind``
 
-A cell, configuration, mix or metric is added with new files and entries
-only: nothing here names one.
+A cell, configuration, architecture, mix or metric is added with new files
+and entries only: nothing here names one.
 """
 from __future__ import annotations
 
@@ -47,6 +53,17 @@ def load_module(path: Path):
 
 def reference(m: dict, bench: Path = BENCH_DIR):
     return load_module(bench / "references" / f"{m['reference']}.py")
+
+
+def arch(m: dict, bench: Path = BENCH_DIR):
+    """The module of configuration ``m``'s architecture, by its
+    ``model_type``. There is no default: a type with no module fails."""
+    path = bench / "arch" / f"{m['model_type']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"{m['name']}: no module for model_type {m['model_type']!r}: "
+            f"{path.relative_to(bench.parent)} does not exist")
+    return load_module(path)
 
 
 def metric_reader(name: str, bench: Path = BENCH_DIR):

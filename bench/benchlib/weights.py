@@ -1,48 +1,26 @@
 """Weights made from ``--seed`` by the benchmark itself, in bfloat16.
 
-The tree has the layout the serving program takes (layers stacked on a
-leading axis). One jitted call makes a whole base model on the device; one
-more makes every expert's own down-projections. Experts share every other
-leaf of the base on the host, so building 8 experts copies the base to the
-host once; each expert still moves its whole tree host->HBM when it is
-switched in, because the host store hands the program whole trees.
-
-Scales: embeddings N(0, 1); every projection N(0, 1/fan_in); norm scales 1.
+The tree, each leaf's shape and its init std (None: a norm scale of ones)
+come from the architecture's ``shapes(m)``, in the layout the serving
+program takes. One jitted call makes a whole base model on the device; one
+more makes every expert's own ``EXPERT_LEAF`` (the architecture's too).
+Leaf ``i`` of the base, in tree order, draws from the ``i``-th split of
+stream 0 of the seed; expert ``i`` from stream ``i + 1``. Experts share
+every other leaf of the base on the host, so building 8 experts copies the
+base to the host once; each expert still moves its whole tree host->HBM
+when it is switched in, because the host store hands the program whole
+trees.
 """
 from __future__ import annotations
 
-import math
-from typing import Dict, List, Tuple
+from pathlib import Path
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-# the one leaf every expert draws afresh (so experts' logits differ
-# entirely, and a request served with another expert's bytes shows)
-EXPERT_LEAF = ("layers", "mlp", "wo")
-
-
-def shapes(m: dict) -> Dict:
-    """Leaf shapes and init std of the tree for model dict ``m``."""
-    D, L, V = m["hidden_size"], m["num_hidden_layers"], m["vocab_size"]
-    Hq, Hkv = m["num_attention_heads"], m["num_key_value_heads"]
-    dh, F = m["head_dim"], m["intermediate_size"]
-    return {
-        "embed": {"tok": ((V, D), 1.0)},
-        "final_norm": {"scale": ((D,), None)},
-        "lm_head": ((D, V), 1 / math.sqrt(D)),
-        "layers": {
-            "attn": {"norm": {"scale": ((L, D), None)},
-                     "wq": ((L, D, Hq, dh), 1 / math.sqrt(D)),
-                     "wk": ((L, D, Hkv, dh), 1 / math.sqrt(D)),
-                     "wv": ((L, D, Hkv, dh), 1 / math.sqrt(D)),
-                     "wo": ((L, Hq, dh, D), 1 / math.sqrt(Hq * dh))},
-            "mlp_norm": {"scale": ((L, D), None)},
-            "mlp": {"wi_gate": ((L, D, F), 1 / math.sqrt(D)),
-                    "wi_up": ((L, D, F), 1 / math.sqrt(D)),
-                    "wo": ((L, F, D), 1 / math.sqrt(F))}},
-    }
+from benchlib import BENCH_DIR, spec
 
 
 def _is_spec(x):
@@ -56,10 +34,10 @@ def _draw(key, shape, std):
         jnp.bfloat16)
 
 
-def make_base(m: dict, seed: int):
+def make_base(m: dict, seed: int, bench: Path = BENCH_DIR):
     """Device tree of the base model for ``seed``, made in one jitted call."""
-    spec = shapes(m)
-    leaves, treedef = jax.tree.flatten(spec, is_leaf=_is_spec)
+    leaves, treedef = jax.tree.flatten(spec.arch(m, bench).shapes(m),
+                                       is_leaf=_is_spec)
 
     @jax.jit
     def build(key):
@@ -70,10 +48,12 @@ def make_base(m: dict, seed: int):
     return build(_key(seed, 0))
 
 
-def make_expert_leaves(m: dict, seed: int, n: int):
+def make_expert_leaves(m: dict, seed: int, n: int,
+                       bench: Path = BENCH_DIR):
     """Device array ``(n, ...)`` of each expert's own ``EXPERT_LEAF``, made
     in one jitted call; expert ``i`` draws from stream ``i + 1``."""
-    s, std = _get(shapes(m), EXPERT_LEAF)
+    arch = spec.arch(m, bench)
+    s, std = _get(arch.shapes(m), arch.EXPERT_LEAF)
     keys = jnp.stack([_key(seed, i + 1) for i in range(n)])
     return jax.jit(jax.vmap(lambda k: _draw(k, s, std)))(keys)
 
@@ -97,15 +77,17 @@ def _with(tree, path, value):
     return out
 
 
-def build_experts(m: dict, n_experts: int, seed: int
+def build_experts(m: dict, n_experts: int, seed: int,
+                  bench: Path = BENCH_DIR
                   ) -> Tuple[List[Tuple[str, dict]], int]:
     """Host trees of ``n_experts`` experts for ``seed``: ``[(name, tree)]``
     and the bytes of one expert. With one expert, the base is the expert."""
-    base = jax.device_get(make_base(m, seed))
+    base = jax.device_get(make_base(m, seed, bench))
     nbytes = sum(x.nbytes for x in jax.tree.leaves(base))
     if n_experts == 1:
         return [("expert000", base)], nbytes
-    leaves = np.asarray(jax.device_get(make_expert_leaves(m, seed,
-                                                          n_experts)))
-    return [(f"expert{i:03d}", _with(base, EXPERT_LEAF, leaves[i]))
+    leaves = np.asarray(jax.device_get(make_expert_leaves(m, seed, n_experts,
+                                                          bench)))
+    leaf = spec.arch(m, bench).EXPERT_LEAF
+    return [(f"expert{i:03d}", _with(base, leaf, leaves[i]))
             for i in range(n_experts)], nbytes

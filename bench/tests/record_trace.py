@@ -25,9 +25,9 @@ from pathlib import Path
 BENCH = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(BENCH.parent / "src"), str(BENCH)]
 
-WIDTHS = ("hidden_size", "num_hidden_layers", "num_attention_heads",
-          "num_key_value_heads", "head_dim", "intermediate_size",
-          "vocab_size", "deployment")
+WIDTHS = ("model_type", "hidden_size", "num_hidden_layers",
+          "num_attention_heads", "num_key_value_heads", "head_dim",
+          "intermediate_size", "vocab_size", "deployment")
 
 
 def trim(ev: dict, keep_ns: int) -> dict:

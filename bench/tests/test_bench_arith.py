@@ -8,7 +8,7 @@ from toy import GRANITE, ROOT
 from benchlib import endtoend, rooflines, spec
 from benchlib.drive import Record
 
-M = GRANITE
+M = dict(GRANITE, model_type="llama")
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 

@@ -95,15 +95,39 @@ def test_reference_agrees_with_the_program_in_float32(toy_root):
     """The benchmark's reference and the program's own float32 forward
     give the same logits on the same weights (a toy size, on the CPU)."""
     from benchlib import weights
-    from benchlib.deploy import model_config
     from repro.models.reference import reference_logits
-    m = spec.config("toy-llama", toy_root / "bench")
-    params = jax.device_get(weights.make_base(m, 5))
-    ref = spec.reference(m, toy_root / "bench")
+    bench = toy_root / "bench"
+    m = spec.config("toy-llama", bench)
+    params = jax.device_get(weights.make_base(m, 5, bench))
+    ref = spec.reference(m, bench)
     toks = np.random.RandomState(0).randint(0, m["vocab_size"], 40)
-    got = reference_logits(model_config(m), params, toks, pad_to=64)
+    got = reference_logits(spec.arch(m, bench).model_config(m), params, toks,
+                           pad_to=64)
     served = np.argmax(got[19:38], -1).astype(np.int32)
     gaps = ref.gaps(m, params, toks[:39], 19, served, 64)
     assert np.max(gaps) < 1e-4
     wrong = (served + 1) % m["vocab_size"]
     assert np.min(ref.gaps(m, params, toks[:39], 19, wrong, 64)) > 1e-3
+
+
+def test_close_frees_every_array_the_pool_holds(toy_root):
+    """The reference runs in the room the program held: closing the
+    deployment frees each device array of the K/V pool, whatever the pool
+    names it, though the engine sits in reference cycles."""
+    from benchlib import traffic
+    from benchlib.deploy import Deployment
+    bench = toy_root / "bench"
+    m = spec.config("toy-llama", bench)
+    tp = spec.traffic("toy-mix", bench)
+    dep = Deployment(m, 3, bench)
+    t = traffic.generate(tp, 3, dep.expert_names(), m["vocab_size"], 1.0)
+    dep.warm(t.popularity, cellmod.max_pack(m, tp))
+    pool = dep.engine.pool
+    pool.latent = jnp.full((3, 5, 7), 1.5, jnp.float32)
+    kinds = [(x.shape, x.dtype) for x in vars(pool).values()
+             if isinstance(x, jax.Array)]
+    del pool
+    held = lambda: sum((a.shape, a.dtype) in kinds for a in jax.live_arrays())
+    before = held()
+    dep.close()
+    assert before - held() == len(kinds) >= 3

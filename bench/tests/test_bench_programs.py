@@ -186,7 +186,7 @@ def test_recorded_program_trace(path):
     for k, v in programs.summary(pr, red).items():
         assert v == pytest.approx(want["readings"][k], rel=1e-6), k
     # each fused kernel runs under its own scope of the decode step
-    names = rooflines.names(red, want["model"])
+    names = rooflines.names(red, dict(want["model"], model_type="llama"))
     for op, kernel in names.items():
         i = next(j for j, x in enumerate(ev["devices"]["0"]) if x[0] == op)
         assert ev["op_meta"]["0"][i] == ["decode_step",

@@ -48,14 +48,12 @@ def test_exits_nonzero_in_a_bare_checkout(tmp_path):
 @pytest.mark.parametrize("name", [c["name"] for c in spec.benchmark()[
     "configs"]])
 def test_configs_build_a_model_the_fused_backend_takes(name):
-    from benchlib.deploy import model_config
     from repro.serving.backends import FusedPagedBackend
     m = spec.config(name)
-    cfg = model_config(m)
-    FusedPagedBackend(cfg, scratch_row=0)
-    assert cfg.d_model == m["hidden_size"]
-    assert cfg.n_kv_heads == m["num_key_value_heads"]
-    assert m["head_dim"] * m["num_attention_heads"] == m["hidden_size"]
+    arch = spec.arch(m)
+    arch.check_config(m)
+    if m["model_type"] == "llama":
+        FusedPagedBackend(arch.model_config(m), scratch_row=0)
     for k in m["reduced"]:
         assert m["published"][k] != m[k]
 

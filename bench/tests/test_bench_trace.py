@@ -80,7 +80,7 @@ def test_recorded_chip_trace(path):
     ev = json.loads(gzip.decompress(path.read_bytes()))
     want = json.loads(path.with_name(
         path.name.replace(".events.json.gz", ".counts.json")).read_text())
-    m = want["model"]
+    m = dict(want["model"], model_type="llama")
     red = tracing.reduce(ev)
     assert red["window_s"] == pytest.approx(want["window_s"], rel=1e-9)
     assert red["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
